@@ -5,6 +5,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments", "bench")
 
 

@@ -109,16 +109,21 @@ def _naive_makespan(dag) -> float:
     return max(lp.values())
 
 
-def _launch_rows(rows, kmax: int, num_t: int, impl: str) -> int:
+def _launch_rows(rows, kmax: int, num_t: int, impl: str) -> list:
     """Solve one row set the engine's way: stack, pad to the row bucket,
     ONE ``row_pgd_step`` launch per family group. Mirrors
-    ``WorkflowEngine._solve_tick`` so the timed work is the same."""
+    ``WorkflowEngine._solve_tick`` so the timed work is the same.
+
+    Returns one ``(idx, mu, var, W_next)`` per family group: the group's
+    indices into ``rows`` and the launch's outputs for those real rows
+    (bucket padding sliced off)."""
     from repro.kernels import autotune
     from repro.serve.engine import row_pgd_step
     from repro.workflow.solve import stack_rows
 
     groups, mask, km = stack_rows(
         [(r.mus, r.sigmas, r.family) for r in rows], kmax=kmax)
+    outs = []
     for g in groups:
         n = len(g.idx)
         F = autotune.bucket_rows(n)
@@ -139,9 +144,10 @@ def _launch_rows(rows, kmax: int, num_t: int, impl: str) -> int:
             W[n:], mus[n:], sgs[n:] = W[0], mus[0], sgs[0]
             ex[:, n:] = ex[:, :1]
             msk[n:], lam[n:] = msk[0], lam[0]
-        row_pgd_step(W, mus, sgs, g.dist_id, ex, lam, msk,
-                     num_t=num_t, impl=impl)
-    return len(groups)
+        m, v, W2 = row_pgd_step(W, mus, sgs, g.dist_id, ex, lam, msk,
+                                num_t=num_t, impl=impl)
+        outs.append((g.idx, m[:n], v[:n], W2[:n]))
+    return outs
 
 
 def _solve_batched(rows, kmax: int, num_t: int, impl: str) -> None:

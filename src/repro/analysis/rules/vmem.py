@@ -5,8 +5,9 @@ latent hazard: a ``block_f`` default that fits the forward kernel can
 overflow VMEM the moment differentiation swaps in the full-parameter fused
 launch. This rule runs the SAME working-set model the runtime autotuner uses
 (:func:`repro.kernels.autotune.vmem_bytes`) at lint time, over every
-family x mode x stacked combination, so the "pgrad needs its own safe block"
-footnote is a hard check instead of tribal knowledge.
+family x mode combination (shared and per-row statistics occupy the same
+(K, block_f) tiles, so the layout adds no axis), so the "pgrad needs its
+own safe block" footnote is a hard check instead of tribal knowledge.
 
 A *launch wrapper* is any function whose body calls ``pl.pallas_call``. Its
 modes come from its signature: a ``param_grads`` parameter means the fused
@@ -16,7 +17,7 @@ points — the documented scale target every default must survive.
 
 * **RPA030** — the wrapper's default ``block_f`` overflows the VMEM budget
   for at least one audited combination; the message names every failing
-  (family, mode, stacked) tuple and the largest candidate block that fits
+  (family, mode) pair and the largest candidate block that fits
   them all.
 * **RPA031** — the wrapper derives its grid from ``block_f`` (``F //
   block_f``) but neither it nor a same-file helper it passes ``block_f`` to
@@ -144,33 +145,25 @@ class VmemBlockSpecRule:
     def _check_budget(self, ctx, fn, bf, families, autotune,
                       budget) -> Iterator[Finding]:
         modes = _audit_modes("param_grads" in param_names(fn.args))
-        failing = []
-        infeasible = []
-        for fam in families:
-            for mode, fused, params in modes:
-                for stacked in (False, True):
-                    need = autotune.vmem_bytes(bf, _AUDIT_K, _AUDIT_T, fused,
-                                               fam, params, stacked)
-                    if need > budget:
-                        failing.append((fam, mode, stacked, need))
-                    fits = [c for c in autotune.BLOCK_F_CANDIDATES
-                            if autotune.vmem_bytes(c, _AUDIT_K, _AUDIT_T,
-                                                   fused, fam, params,
-                                                   stacked) <= budget]
-                    if not fits:
-                        infeasible.append((fam, mode, stacked))
+        combos = [(fam, mode, fused, params) for fam in families
+                  for mode, fused, params in modes]
+
+        def need(c, fam, fused, params):
+            return autotune.vmem_bytes(c, _AUDIT_K, _AUDIT_T, fused, fam,
+                                       params)
+
+        failing = [(fam, mode, need(bf, fam, fused, params))
+                   for fam, mode, fused, params in combos
+                   if need(bf, fam, fused, params) > budget]
+        infeasible = [(fam, mode) for fam, mode, fused, params in combos
+                      if all(need(c, fam, fused, params) > budget
+                             for c in autotune.BLOCK_F_CANDIDATES)]
         if failing:
             safe = [c for c in autotune.BLOCK_F_CANDIDATES
-                    if all(autotune.vmem_bytes(
-                        c, _AUDIT_K, _AUDIT_T, fused, fam, params, stacked)
-                        <= budget
-                        for fam in families
-                        for _, fused, params in modes
-                        for stacked in (False, True))]
-            combos = ", ".join(
-                f"{fam}/{mode}{':stk' if stacked else ''}"
-                f"={need / 2**20:.1f}MB"
-                for fam, mode, stacked, need in failing[:4])
+                    if all(need(c, fam, fused, params) <= budget
+                           for fam, _, fused, params in combos)]
+            listed = ", ".join(f"{fam}/{mode}={n / 2**20:.1f}MB"
+                               for fam, mode, n in failing[:4])
             more = f" (+{len(failing) - 4} more)" if len(failing) > 4 else ""
             hint = (f"largest block fitting every combo is {max(safe)}"
                     if safe else "no candidate fits every combo")
@@ -178,10 +171,10 @@ class VmemBlockSpecRule:
                 fn, "RPA030",
                 f"'{fn.name}' default block_f={bf} overflows the "
                 f"{budget / 2**20:.1f}MB VMEM budget at "
-                f"K={_AUDIT_K}/T={_AUDIT_T} for {combos}{more}; {hint}")
-        for fam, mode, stacked in infeasible:
+                f"K={_AUDIT_K}/T={_AUDIT_T} for {listed}{more}; {hint}")
+        for fam, mode in infeasible:
             yield ctx.finding(
                 fn, "RPA032",
                 f"'{fn.name}': no candidate block_f fits the VMEM budget for "
-                f"{fam}/{mode}{':stk' if stacked else ''} at "
-                f"K={_AUDIT_K}/T={_AUDIT_T} — working set needs rework")
+                f"{fam}/{mode} at K={_AUDIT_K}/T={_AUDIT_T} — working set "
+                f"needs rework")

@@ -1,12 +1,7 @@
 """Version compatibility shims for the installed jax.
 
-The codebase targets the current jax API surface; this module backfills the
-pieces that moved or were renamed so it also runs on jax 0.4.x:
-
-* ``shard_map`` — promoted to ``jax.shard_map`` in 0.5 with ``axis_names``
-  (axes to run Manual) and ``check_vma``; 0.4.x has
-  ``jax.experimental.shard_map.shard_map`` with the complementary ``auto``
-  set and ``check_rep``.
+* ``shard_map`` — ``jax.shard_map`` with ``axis_names`` given as any
+  iterable (the axes to run Manual; None = all of them).
 """
 from __future__ import annotations
 
@@ -17,19 +12,8 @@ __all__ = ["shard_map"]
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """``jax.shard_map`` facade that also drives the 0.4.x experimental API.
-
-    ``axis_names``: mesh axes mapped Manual inside ``f`` (None = all of them),
-    matching the jax >= 0.5 keyword. ``check_vma`` maps to 0.4.x ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    """``jax.shard_map`` with ``axis_names`` as any iterable of mesh axes
+    mapped Manual inside ``f`` (None = all of them)."""
+    kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)

@@ -72,7 +72,7 @@ the one expression in :func:`point_mass_cdf`; the quadrature oracles and both
 Pallas kernels share it rather than re-deriving the comparison locally.
 
 All functions are pure jnp, broadcasting-agnostic (the vectorized (F, T, K)
-reference path and the Pallas kernels' (block_f, T) per-channel slices call
+reference path and the Pallas kernels' (T, block_f) per-channel slices call
 the same code) and differentiable where the math is.
 """
 from __future__ import annotations
@@ -148,14 +148,43 @@ def phi(x: jax.Array) -> jax.Array:
     return jnp.exp(-0.5 * x * x) / _SQRT_2PI
 
 
+# erfc(x) = t exp(-x^2 + P(t)) with t = 1/(1 + x/2) for x >= 0: the Chebyshev
+# fit of Press et al., Numerical Recipes (2nd ed., section 6.2, ``erfcc``),
+# fractional error < 1.2e-7 for every x >= 0. The error is RELATIVE, so the
+# lower tail of Phi keeps its digits down to the kernels' CDF floor instead of
+# cancelling to 0 in 0.5 * (1 + erf). Only mul/add/div/exp: Mosaic (the TPU
+# kernel compiler) has no erf, and this single definition is what the Pallas
+# kernels, the pure-jnp oracles and the XLA path all evaluate.
+_ERFC_COEFFS = (-1.26551223, 1.00002368, 0.37409196, 0.09678418, -0.18628806,
+                0.27886807, -1.13520398, 1.48851587, -0.82215223, 0.17087277)
+
+
+def _erfc_nonneg(x):
+    """erfc(x) for x >= 0 (see ``_ERFC_COEFFS``)."""
+    t = 1.0 / (1.0 + 0.5 * x)
+    poly = _ERFC_COEFFS[-1]
+    for c in reversed(_ERFC_COEFFS[:-1]):
+        poly = c + t * poly
+    return t * jnp.exp(poly - x * x)
+
+
+@jax.custom_jvp
 def Phi(x: jax.Array) -> jax.Array:
-    """Standard normal cdf via erf (TPU/VPU friendly; no erfc tables)."""
-    return 0.5 * (1.0 + jax.lax.erf(x / _SQRT2))
+    """Standard normal cdf, accurate to < 1e-6 absolute and 1.2e-7 relative
+    in the lower tail; its derivative is exactly :func:`phi`."""
+    tail = 0.5 * _erfc_nonneg(jnp.abs(x) / _SQRT2)   # P(Z > |x|)
+    return jnp.where(x < 0.0, tail, 1.0 - tail)
+
+
+@Phi.defjvp
+def _Phi_jvp(primals, tangents):
+    (x,), (dx,) = primals, tangents
+    return Phi(x), phi(x) * dx
 
 
 def Phi_c(x: jax.Array) -> jax.Array:
     """Standard normal survival function 1 - Phi(x), numerically stable tail."""
-    return 0.5 * jax.lax.erfc(x / _SQRT2)
+    return Phi(-x)
 
 
 def log_Phi(x: jax.Array) -> jax.Array:
@@ -520,7 +549,7 @@ def family_accumulators(dist_id: str) -> Tuple[bool, bool]:
     coefficients, P1/Pv1 the t-weighted (beta, gamma1) ones. Pure scale
     families (normal, empirical) and drift keep P1; lognormal's log-space
     z-score is t-free in dw and needs P0 instead; drift's affine dz/dw needs
-    both — 4 live (block_f, K) accumulators instead of 2, which is why the
+    both — 4 live (K, block_f) accumulators instead of 2, which is why the
     family is part of the autotune working-set model and cache key. The
     full-parameter adjoint needs the wider :func:`family_features` basis.
     """
@@ -533,7 +562,7 @@ def family_features(dist_id: str, params: bool = False
     """Accumulator basis the fused adjoint contracts against.
 
     Returns ``(use_1, use_t, use_z)``: every live feature f costs a
-    ``(block_f, K)`` accumulator pair (``Pf`` for the mu cotangent, ``Pvf``
+    ``(K, block_f)`` accumulator pair (``Pf`` for the mu cotangent, ``Pvf``
     for the fused var cotangent). With ``params=False`` (W-gradients only —
     the PGD path) this is the legacy :func:`family_accumulators` set; with
     ``params=True`` the mus/sigmas/extra adjoints widen the basis:

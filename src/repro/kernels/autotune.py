@@ -1,10 +1,9 @@
 """block_f autotuning for the frontier kernels.
 
-PR 1 hard-coded ``block_f=128`` for every launch. That was safe when a
-program's working set was one (block_f, T) survival tile plus a (block_f, K)
-weight tile; the fused moments+gradient kernel holds ~3x that (two per-channel
-accumulators and two (block_f, K) gradient outputs live in the same VMEM
-tile), so the right block size now depends on (K, num_t, backend, fused) —
+A program's working set is a (T, block_f) survival tile plus (K, block_f)
+channel tiles; the fused moments+gradient kernel holds several times that
+(per-channel accumulators and (K, block_f) gradient outputs live in the same
+VMEM), so the right block size depends on (K, num_t, backend, mode) —
 too big overflows VMEM on TPU (or blows the per-block peak-memory budget of
 the chunked XLA path on CPU), too small wastes launches on grid overhead.
 
@@ -14,8 +13,8 @@ Three layers, cheapest first:
    arithmetic, used whenever ``ops.frontier_moments`` is called without an
    explicit ``block_f``. Deterministic per shape, safe to consult at trace
    time inside jit.
-2. An **in-process cache** keyed by ``(F, K, num_t, backend, fused, dist_id)``
-   so the model (or a sweep result) is computed once per process.
+2. An **in-process cache** keyed by ``(device, backend, F, K, num_t, mode,
+   dist_id)`` so the model (or a sweep result) is computed once per process.
 3. A **timed sweep** (:func:`sweep`) over ``block_f in {32..512}`` x the
    requested ``num_t`` that benchmarks the real kernel on synthetic data and
    persists the winner to ``experiments/bench/autotune_cache.json`` — run by
@@ -29,12 +28,14 @@ adjoint carries two per-channel accumulator pairs for the ``drift`` family
 block sizes. So is the launch *mode*: ``fwd`` (forward moments only),
 ``grad`` (fused W-adjoints — the PGD tick) and ``pgrad`` (full-parameter
 adjoints for the estimation loop: up to six accumulator pairs plus six more
-(block_f, K) output tiles, the largest working set of the three). Cache keys
-are versioned (``v3:``); v2 (family-aware, fused-flag) keys and legacy
-un-versioned keys from the pre-family schema are migrated on load — v2
-``fused0/fused1`` map to ``fwd``/``grad`` (``pgrad`` shapes never existed
-before v3), un-versioned keys additionally pick up the normal family — so an
-existing JSON cache survives both schema bumps.
+(K, block_f) output tiles, the largest working set of the three). So is the
+device: a sweep timed on one device kind never chooses another's launch
+(``device_tag``). Cache keys are versioned (``v4:``); older keys migrate on
+load — v2 ``fused0/fused1`` map to ``fwd``/``grad`` (``pgrad`` shapes never
+existed before v3), un-versioned keys additionally pick up the normal
+family, and every pre-v4 key was timed on the CPU backend, so it migrates
+to the ``cpu`` device — so an existing JSON cache survives every schema
+bump without steering an accelerator.
 """
 from __future__ import annotations
 
@@ -49,6 +50,7 @@ from typing import Dict, Optional, Sequence, Tuple
 _log = logging.getLogger(__name__)
 
 __all__ = ["BLOCK_F_CANDIDATES", "ROW_BUCKETS", "vmem_bytes", "pick_block_f",
+           "device_tag",
            "bucket_rows", "lookup", "sweep", "clear_cache",
            "default_cache_path", "cache_state", "load_cache_state"]
 
@@ -59,14 +61,17 @@ BLOCK_F_CANDIDATES: Tuple[int, ...] = (32, 64, 128, 256, 512)
 ROW_BUCKETS: Tuple[int, ...] = (8, 16, 32, 64, 128, 256, 512, 1024, 2048,
                                 4096)
 
-# v5e-class VMEM is ~16 MB/core; leave headroom for double buffering and the
-# compiler's own temporaries
-_VMEM_BUDGET_BYTES = int(16 * 1024 * 1024 * 0.75)
+# v5e has 128 MiB of VMEM per core (its compiler refuses a larger allocation).
+# The Pallas launches pass this budget as their scoped-VMEM limit, and
+# vmem_bytes models every buffer of a program against it — double-buffered
+# blocks included, which is more than the limit itself counts — so a block
+# the model accepts compiles, with the rest of VMEM to spare.
+_VMEM_BUDGET_BYTES = 48 * 1024 * 1024
 # the XLA path is bounded by host/device peak memory per lax.map block, not
 # VMEM — a much looser working-set ceiling (the (bf, T, K) intermediates)
 _XLA_BLOCK_BUDGET_BYTES = 1024 * 1024 * 1024
 
-_KEY_VERSION = "v3"  # v3: mode-aware keys (fwd | grad | pgrad)
+_KEY_VERSION = "v4"  # v4: device-aware keys; v3: mode-aware keys
 
 _CACHE: Dict[str, dict] = {}
 _JSON_LOADED: set = set()
@@ -84,15 +89,21 @@ def _mode(fused: bool, params: bool) -> str:
     return "pgrad" if params else "grad"
 
 
+def device_tag() -> str:
+    """The default device's kind as a key component (``cpu``,
+    ``tpu_v5_lite``): launch shapes are timed and chosen per device kind."""
+    import jax
+    return jax.devices()[0].device_kind.lower().replace(" ", "_")
+
+
 def _key(F: int, K: int, num_t: int, backend: str, fused: bool,
          dist_id: str = "normal", params: bool = False,
          stacked: bool = False) -> str:
-    # the stacked (per-row statistics) layout holds 2+E more (bf, K) input
-    # tiles per program; its suffix is additive so every existing v3 key
-    # stays valid verbatim — no migration needed
+    # the stacked (per-row statistics) layout streams 2+E more (K, bf)
+    # input tiles from HBM per program; its suffix is additive
     suffix = ":stk" if stacked else ""
-    return (f"{_KEY_VERSION}:{backend}:F{F}:K{K}:T{num_t}"
-            f":mode{_mode(fused, params)}:fam{dist_id}{suffix}")
+    return (f"{_KEY_VERSION}:{device_tag()}:{backend}:F{F}:K{K}"
+            f":T{num_t}:mode{_mode(fused, params)}:fam{dist_id}{suffix}")
 
 
 _V2_RE = re.compile(r"^v2:(?P<body>.*):fused(?P<fused>[01]):fam(?P<fam>\w+)$")
@@ -100,24 +111,27 @@ _LEGACY_RE = re.compile(r"^(?P<body>[^:]+:F\d+:K\d+:T\d+):fused(?P<fused>[01])$"
 
 
 def _migrate_key(k: str) -> str:
-    """Lift a v2 (fused-flag) or legacy (pre-family, un-versioned) key to v3.
+    """Lift a v3, v2 (fused-flag) or legacy (pre-family, un-versioned) key
+    to v4.
 
     v2 ``fused0``/``fused1`` become ``modefwd``/``modegrad`` (the pgrad mode
     is new in v3, so no v2 entry can alias it); un-versioned legacy keys are
-    additionally normal-family.
+    additionally normal-family. Keys older than v4 name no device: all of
+    them were timed on the CPU backend, so they become ``cpu`` entries.
     """
     if k.startswith(f"{_KEY_VERSION}:"):
         return k
     m = _V2_RE.match(k)
     if m:
         mode = "grad" if m.group("fused") == "1" else "fwd"
-        return (f"{_KEY_VERSION}:{m.group('body')}:mode{mode}"
-                f":fam{m.group('fam')}")
+        k = f"v3:{m.group('body')}:mode{mode}:fam{m.group('fam')}"
     m = _LEGACY_RE.match(k)
     if m:
         mode = "grad" if m.group("fused") == "1" else "fwd"
-        return f"{_KEY_VERSION}:{m.group('body')}:mode{mode}:famnormal"
-    return k  # unknown schema: keep verbatim (never collides with v3 keys)
+        k = f"v3:{m.group('body')}:mode{mode}:famnormal"
+    if k.startswith("v3:"):
+        return f"{_KEY_VERSION}:cpu:{k[len('v3:'):]}"
+    return k  # unknown schema: keep verbatim (never collides with v4 keys)
 
 
 def _grad_acc_pairs(dist_id: str, params: bool = False) -> int:
@@ -134,33 +148,44 @@ def _mix_tiles(dist_id: str) -> int:
     return EMP_COMPONENTS - 1 if dist_id == "empirical" else 0
 
 
-def vmem_bytes(block_f: int, num_k: int, num_t: int, fused: bool = False,
-               dist_id: str = "normal", params: bool = False,
-               stacked: bool = False) -> int:
-    """Working-set model of one kernel program, in bytes (f32).
+def _vmem_tile(rows: int, cols: int) -> int:
+    """Bytes of one f32 VMEM buffer: (8, 128) tiles, padded on both axes."""
+    return 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
 
-    Forward: W/means/stds (bf, K) tiles + ts/logF/surv/tsurv (bf, T) tiles.
-    Fused adds the per-channel accumulators and both gradient outputs in
-    (bf, K) plus the weighted-CDF / t(t-mu) work tiles in (bf, T). The family
-    moves both axes: ``drift`` carries FOUR accumulators (P0/P1/Pv0/Pv1)
-    where the scale-like families carry two, and the ``empirical`` mixture
-    holds C-1 extra per-component tiles live per channel step — which is why
-    the family is part of the autotune key. Full-parameter mode (``params``)
-    widens the basis again (the z feature of lognormal and defective: up to
-    three accumulator pairs, six live (bf, K) accumulators — defective's
-    {1, t, z} basis is the widest of any family) and adds the six
-    channel-statistic gradient output tiles — the ``pgrad`` key mode. The ``stacked``
-    (per-row statistics) layout grows the mus/sigmas tiles from (1, K) to
-    (bf, K) and the extra tile to (E, bf, K): 1 + E more (bf, K)-equivalents
-    per program (one of the two stat tiles was already counted).
+
+def vmem_bytes(block_f: int, num_k: int, num_t: int, fused: bool = False,
+               dist_id: str = "normal", params: bool = False) -> int:
+    """VMEM one kernel program allocates, in bytes (f32), in the
+    channel-major layout of ``frontier_grid``.
+
+    * Blocks, each double-buffered by the grid pipeline: the (K, bf) tiles
+      of W, mus, sigmas and the E extra rows (shared statistics arrive
+      lane-broadcast to the same (K, bf) tile as per-row ones); the two
+      (1, bf) moment rows; and in fused modes the (K, bf) gradient outputs
+      — two, or eight with ``params`` (the ``pgrad`` key mode).
+    * Scratch of the fused modes: the (K, bf) reach rows and one (K, bf)
+      accumulator pair per live feature — two pairs for ``drift``, up to
+      three in full-parameter mode (the z feature of ``lognormal`` and
+      ``defective``; defective's {1, t, z} basis is the widest of any
+      family).
+    * Work tiles: the (T, bf) values live in a K-loop step (time grid, log
+      joint CDF, z-scores, the family CDF), more in the fused passes, plus
+      the ``empirical`` mixture's per-component tiles.
+
+    Lanes pad to 128, so every block_f below 128 costs what 128 does. The
+    compiler's scoped-VMEM check counts less than this (not every block),
+    so a program this model fits within ``_VMEM_BUDGET_BYTES`` compiles
+    under that limit.
     """
-    acc = 2 * _grad_acc_pairs(dist_id, params)  # accumulators + grad outputs
-    per_fk = (6 + acc + (6 if params else 0)) if fused else 3
-    if stacked:
-        from repro.core.distributions import extra_rows
-        per_fk += 1 + extra_rows(dist_id)
-    per_ft = (6 if fused else 4) + _mix_tiles(dist_id)
-    return 4 * block_f * (per_fk * num_k + per_ft * num_t)
+    from repro.core.distributions import extra_rows
+    chan = _vmem_tile(num_k, block_f)
+    blocks = (3 + extra_rows(dist_id)) * chan + 2 * _vmem_tile(1, block_f)
+    scratch = 0
+    if fused:
+        blocks += (8 if params else 2) * chan
+        scratch = (1 + 2 * _grad_acc_pairs(dist_id, params)) * chan
+    work = (12 if fused else 8) + 2 * _mix_tiles(dist_id)
+    return 2 * blocks + scratch + work * _vmem_tile(num_t, block_f)
 
 
 def _xla_block_bytes(block_f: int, num_k: int, num_t: int, fused: bool,
@@ -174,24 +199,21 @@ def _xla_block_bytes(block_f: int, num_k: int, num_t: int, fused: bool,
 
 
 def _fits(block_f: int, K: int, num_t: int, backend: str, fused: bool,
-          dist_id: str = "normal", params: bool = False,
-          stacked: bool = False) -> bool:
+          dist_id: str = "normal", params: bool = False) -> bool:
     if backend == "xla":
         return (_xla_block_bytes(block_f, K, num_t, fused, dist_id, params)
                 <= _XLA_BLOCK_BUDGET_BYTES)
-    return (vmem_bytes(block_f, K, num_t, fused, dist_id, params, stacked)
+    return (vmem_bytes(block_f, K, num_t, fused, dist_id, params)
             <= _VMEM_BUDGET_BYTES)
 
 
 def pick_block_f(F: int, K: int, num_t: int, backend: str = "xla",
                  fused: bool = False,
                  candidates: Sequence[int] = BLOCK_F_CANDIDATES,
-                 dist_id: str = "normal", params: bool = False,
-                 stacked: bool = False) -> int:
+                 dist_id: str = "normal", params: bool = False) -> int:
     """Largest candidate block_f that fits the backend's budget model."""
     feasible = [bf for bf in candidates
-                if _fits(bf, K, num_t, backend, fused, dist_id, params,
-                         stacked)]
+                if _fits(bf, K, num_t, backend, fused, dist_id, params)]
     pick = max(feasible) if feasible else min(candidates)
     return max(min(pick, F), 1)
 
@@ -253,8 +275,8 @@ def lookup(F: int, K: int, num_t: int, backend: str = "xla",
     trace-safe); :func:`sweep` feeds better-than-model entries into the same
     caches. ``params`` selects the full-parameter-adjoint (``pgrad``) launch
     mode the estimation loop's custom VJP uses; ``stacked`` the per-row
-    statistics layout (its own key suffix — a block tuned for broadcast
-    stats must not be handed to the larger stacked working set).
+    statistics layout (its own key suffix: the two layouts read different
+    amounts of HBM, so a sweep of one does not time the other).
     """
     _load_json(cache_path or default_cache_path())
     key = _key(F, K, num_t, backend, fused, dist_id, params, stacked)
@@ -264,7 +286,7 @@ def lookup(F: int, K: int, num_t: int, backend: str = "xla",
         return max(min(int(hit["block_f"]), F), 1)
     _LOOKUP_LOCAL.outcome = "miss"
     bf = pick_block_f(F, K, num_t, backend, fused, dist_id=dist_id,
-                      params=params, stacked=stacked)
+                      params=params)
     _log.debug(
         "autotune cache miss: F=%d K=%d num_t=%d backend=%s dist_id=%s "
         "mode=%s stacked=%s -> model block_f=%d (run autotune.sweep to "
@@ -341,7 +363,7 @@ def sweep(F: int, K: int, num_t: int, backend: str = "xla",
     disk = {}
     try:
         with open(path) as f:
-            # normalize any legacy keys on rewrite so the file converges to v2
+            # normalize older keys on rewrite so the file converges to v4
             disk = {_migrate_key(k): v for k, v in json.load(f).items()}
     except (OSError, ValueError):
         pass

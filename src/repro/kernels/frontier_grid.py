@@ -10,17 +10,22 @@ tick (posteriors move every step). That is a dense (F x T x K) computation of
 erf/exp/log with two reductions — VPU-bound, and exactly the kind of loop worth
 tiling into VMEM instead of bouncing (F, T, K) intermediates through HBM.
 
-Tiling: the candidate axis F is blocked (block_f rows per program); each
-program holds a (block_f, T) survival accumulator in VMEM and streams the K
-channels in registers via a fori_loop, adding each channel's log-CDF. T and K
-are small enough (T<=2048, K<=4096) that one tile's working set
-block_f*(T)*4B stays well under the ~16 MB v5e VMEM budget for block_f<=256.
-The fused gradient kernel additionally carries per-channel (block_f, K)
-accumulators — two for the scale-like families, FOUR for ``drift`` (see the
-derivation below) — plus the (block_f, K) gradient outputs, which is why
-``kernels.autotune`` keys its working-set model and cache on
-``(shape, backend, fused, dist_id)`` and picks a smaller block_f for the
-fused and drift variants.
+Tiling: the candidate axis F is blocked (block_f rows per program) and laid
+out channel-major: every per-channel array enters a program as a (K, block_f)
+tile — channels on sublanes, candidates on lanes — and the survival
+accumulator is a (T, block_f) tile with time on sublanes. The K channels
+stream through a fori_loop that reads channel ``kk`` as the (1, block_f) row
+``ref[pl.ds(kk, 1), :]`` (a dynamic sublane offset, which Mosaic lowers to
+one load; a dynamic lane offset into a (block_f, K) tile would not lower)
+and adds its log-CDF. Shared channel statistics arrive as one (K, block_f)
+block that every program reads the same way. Reductions over T are sublane reductions, and the moments leave as
+lane-dense (1, block_f) rows. The fused gradient kernel writes each
+channel's accumulator row into (K, block_f) VMEM scratch — two for the
+scale-like families, FOUR for ``drift`` (see the derivation below) — and its
+epilogue walks K in 8-row sublane chunks, so no (K, block_f) temporary is
+ever live. ``kernels.autotune.vmem_bytes`` models every buffer of that
+layout (double-buffered blocks, scratch, (T, block_f) work tiles) and the
+launch passes the same budget to the compiler as its scoped-VMEM limit.
 
 Per-candidate integration grids (t in [0, tmax_f]) keep accuracy uniform
 across candidates whose means differ by orders of magnitude; ``tmax`` uses the
@@ -110,7 +115,7 @@ and every parameter also carries the moving-grid term below with
 dtmax/dtheta_a = dreach_a/dtheta (family_dreach_params: w for mu, z_span*w
 for sigma, mu w^2/2 for rho) on the argmax channel. So full-parameter mode
 (static ``param_grads=True``) is the same two-pass streaming kernel with at
-most SIX per-channel accumulators instead of four, six extra (block_f, K)
+most SIX per-channel accumulators instead of four, six extra (K, block_f)
 output tiles, and an unchanged K-loop count — the accumulators are shared
 across w/mu/sigma/rho; only the epilogue contractions differ. The
 ``empirical`` family's mixture parameters are deliberately NOT adjointed
@@ -140,7 +145,7 @@ conventions (0 below the floor, 0.5 exactly at saturation).
 
 The fused kernel computes the forward pass (one K-loop building log F), then a
 second K-loop accumulating the P*/Pv* sums per channel from the shared
-(block_f, T) joint-CDF tile — so ``(mu, var, dmu_dW, dvar_dW)`` costs ~2
+(T, block_f) joint-CDF tile — so ``(mu, var, dmu_dW, dvar_dW)`` costs ~2
 forward passes in one launch, instead of a forward plus a full autodiff
 replay through the quadrature graph.
 """
@@ -151,9 +156,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["frontier_grid", "frontier_grid_with_grads"]
 
+from . import autotune as _at
 from .ref import _CDF_FLOOR  # single source: kernel must match its oracle
 from repro.core import distributions as dists
 
@@ -178,39 +185,83 @@ def _check_block(F: int, K: int, block_f: int, dist_id: str,
             f"divisibility — call through it, or pass a block_f dividing F.")
 
 
-def _slice_k(arr, kk):
-    # channels always live on the LAST axis: (bf, K) weight/stat tiles,
-    # (E, K) shared extras and (E, bf, K) per-row extras all slice the same
-    return jax.lax.dynamic_slice_in_dim(arr, kk, 1, axis=arr.ndim - 1)
+_SUB = 8  # f32 sublanes per vreg: the channel-chunk height of the K folds
+
+
+def _channel_rows(refs, sl):
+    """(w, mus, sigmas, extra) at channel rows ``sl``, each (rows, bf);
+    ``extra`` as a tuple of its E rows, which the family math indexes like
+    the array (and no 3-D value reaches Mosaic)."""
+    w_ref, mu_ref, sg_ref, ex_ref = refs
+    return (w_ref[sl, :], mu_ref[sl, :], sg_ref[sl, :],
+            tuple(ex_ref[e, sl, :] for e in range(ex_ref.shape[0])))
+
+
+def _fold_channels(num_k: int, body, carry):
+    """Fold ``body(sl, carry)`` over the channels in 8-row sublane chunks at
+    aligned dynamic offsets, then over the static remainder rows."""
+    n = num_k // _SUB
+
+    def chunk(c, acc):
+        return body(pl.ds(pl.multiple_of(c * _SUB, _SUB), _SUB), acc)
+
+    if n:
+        carry = jax.lax.fori_loop(0, n, chunk, carry)
+    if num_k % _SUB:
+        carry = body(pl.ds(n * _SUB, num_k % _SUB), carry)
+    return carry
+
+
+def _prologue(refs, num_t: int, z: float, dist_id: str, reach_ref=None):
+    """Grid end, time grid and trapezoid weights shared by both kernels.
+
+    Returns ``(amax, tmax, ts, wq)``: the unclamped per-candidate grid end
+    (1, bf), its clamp, the (T, bf) time grid and the (T, 1) trapezoid
+    weights (end points folded in at 0.5). ``reach_ref`` keeps every
+    channel's reach for the adjoint's argmax ties, so the epilogue compares
+    the very values the max was taken over.
+    """
+    num_k, block_f = refs[0].shape
+
+    def reach_max(sl, m):
+        me, se = dists.family_effective_moments(
+            dist_id, *_channel_rows(refs, sl))
+        reach = me + z * se
+        if reach_ref is not None:
+            # max over the stored rows: a compiler may evaluate `reach` once
+            # per consumer with different rounding, and a tie must survive
+            reach_ref[sl, :] = reach
+            reach = reach_ref[sl, :]
+        return jnp.maximum(m, jnp.max(reach, axis=0, keepdims=True))
+
+    amax = _fold_channels(num_k, reach_max,
+                          jnp.full((1, block_f), -jnp.inf, jnp.float32))
+    tmax = jnp.maximum(amax, 1e-12)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (num_t, 1), 0)
+    ts = tmax * (idx.astype(jnp.float32) / (num_t - 1))       # (T, bf)
+    wq = jnp.where((idx == 0) | (idx == num_t - 1), 0.5, 1.0)
+    return amax, tmax, ts, wq
+
+
+def _log_joint_cdf(refs, ts, dist_id: str):
+    """log F(t) on the (T, bf) grid: one channel per fori_loop step."""
+    def add_channel(kk, logF):
+        cdf = dists.family_cdf(dist_id, ts,
+                               *_channel_rows(refs, pl.ds(kk, 1)))
+        return logF + jnp.log(jnp.clip(cdf, _CDF_FLOOR, 1.0))
+
+    return jax.lax.fori_loop(0, refs[0].shape[0], add_channel,
+                             jnp.zeros_like(ts))
 
 
 def _frontier_kernel(w_ref, mu_ref, sg_ref, ex_ref, mu_out_ref, var_out_ref, *,
-                     num_t: int, z: float, num_k: int, dist_id: str):
-    w = w_ref[...]            # (bf, K)
-    mus = mu_ref[...]         # (1, K) shared | (bf, K) per-row
-    sgs = sg_ref[...]         # (1, K) shared | (bf, K) per-row
-    ex = ex_ref[...]          # (E, K) shared | (E, bf, K) per-row
-    means_eff, stds_eff = dists.family_effective_moments(dist_id, w, mus, sgs, ex)
-
-    tmax = jnp.maximum(jnp.max(means_eff + z * stds_eff, axis=-1,
-                               keepdims=True), 1e-12)  # (bf, 1)
-    # per-candidate time grid (bf, T): tmax * linspace(0,1,T)
-    frac = jax.lax.broadcasted_iota(jnp.float32, (1, num_t), 1) / (num_t - 1)
-    ts = tmax * frac          # (bf, T)
-
-    def add_channel(kk, logF):
-        cdf = dists.family_cdf(dist_id, ts, _slice_k(w, kk), _slice_k(mus, kk),
-                               _slice_k(sgs, kk), _slice_k(ex, kk))
-        return logF + jnp.log(jnp.clip(cdf, _CDF_FLOOR, 1.0))
-
-    logF = jax.lax.fori_loop(0, num_k, add_channel,
-                             jnp.zeros_like(ts))
-    surv = 1.0 - jnp.exp(logF)  # (bf, T)
-
-    dt = tmax[:, 0] / (num_t - 1)  # (bf,)
-    mu = (jnp.sum(surv, -1) - 0.5 * (surv[:, 0] + surv[:, -1])) * dt
-    tsurv = ts * surv
-    m2 = 2.0 * (jnp.sum(tsurv, -1) - 0.5 * (tsurv[:, 0] + tsurv[:, -1])) * dt
+                     num_t: int, z: float, dist_id: str):
+    refs = (w_ref, mu_ref, sg_ref, ex_ref)   # (K, bf) tiles, extra (E, K, bf)
+    _, tmax, ts, wq = _prologue(refs, num_t, z, dist_id)
+    surv = 1.0 - jnp.exp(_log_joint_cdf(refs, ts, dist_id))  # (T, bf)
+    dt = tmax / (num_t - 1)
+    mu = jnp.sum(wq * surv, axis=0, keepdims=True) * dt
+    m2 = 2.0 * jnp.sum(wq * ts * surv, axis=0, keepdims=True) * dt
     mu_out_ref[...] = mu
     var_out_ref[...] = jnp.maximum(m2 - mu * mu, 0.0)
 
@@ -228,16 +279,58 @@ def _family_extra(dist_id: str, extra, K: int, F=None):
     return extra
 
 
-def _stat_specs(F: int, K: int, E: int, block_f: int, per_row: bool):
-    """BlockSpecs for (mus, sigmas, extra): shared stats broadcast one tile
-    to every program; per-row stats tile along F exactly like W."""
+def _launch_operands(W, mus, sigmas, extra, dist_id: str, block_f: int):
+    """Channel-major kernel operands and their BlockSpecs.
+
+    (F, K) arrays become (G, K, block_f) with G = F // block_f programs, so
+    each program's tile is (K, block_f) whatever block_f is. Shared stats
+    are broadcast across block_f lanes once, here, and every program reads
+    that one block: Mosaic cannot broadcast a (1, 1) value into a (T, bf)
+    tile, so a stat must already be a (1, bf) row when the kernel loads it.
+    Per-row stats tile along F exactly like W.
+    """
+    F, K = W.shape
+    G = F // block_f
+
+    def channel_major(a):  # (..., F, K) -> (G, ..., K, block_f)
+        lead = a.shape[:-2]
+        a = a.reshape(lead + (G, block_f, K))
+        a = jnp.moveaxis(a, len(lead), 0)
+        return jnp.swapaxes(a, -1, -2)
+
+    def lanes(a):  # (..., K) -> (..., K, block_f)
+        return jnp.broadcast_to(a[..., None], a.shape + (block_f,))
+
+    W = W.astype(jnp.float32)
+    mus = jnp.asarray(mus, jnp.float32)
+    sgs = jnp.asarray(sigmas, jnp.float32)
+    per_row = mus.ndim == 2
+    ex = _family_extra(dist_id, extra, K, F if per_row else None)
+    E = ex.shape[0]
+    tile = pl.BlockSpec((None, K, block_f), lambda i: (i, 0, 0))
     if per_row:
-        return [pl.BlockSpec((block_f, K), lambda i: (i, 0)),
-                pl.BlockSpec((block_f, K), lambda i: (i, 0)),
-                pl.BlockSpec((E, block_f, K), lambda i: (0, i, 0))]
-    return [pl.BlockSpec((1, K), lambda i: (0, 0)),
-            pl.BlockSpec((1, K), lambda i: (0, 0)),
-            pl.BlockSpec((E, K), lambda i: (0, 0))]
+        operands = tuple(channel_major(a) for a in (W, mus, sgs, ex))
+        specs = [tile, tile, tile,
+                 pl.BlockSpec((None, E, K, block_f), lambda i: (i, 0, 0, 0))]
+    else:
+        operands = (channel_major(W), lanes(mus), lanes(sgs), lanes(ex))
+        shared = pl.BlockSpec((K, block_f), lambda i: (0, 0))
+        specs = [tile, shared, shared,
+                 pl.BlockSpec((E, K, block_f), lambda i: (0, 0, 0))]
+    return operands, specs
+
+
+def _moment_outputs(G: int, block_f: int):
+    """(specs, shapes) of the two lane-dense (G, 1, block_f) moment rows."""
+    spec = pl.BlockSpec((None, 1, block_f), lambda i: (i, 0, 0))
+    shape = jax.ShapeDtypeStruct((G, 1, block_f), jnp.float32)
+    return [spec, spec], [shape, shape]
+
+
+# the scoped-VMEM limit the autotune model budgets against: every block the
+# model picks fits it by construction
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=_at._VMEM_BUDGET_BYTES)
 
 
 @functools.partial(jax.jit, static_argnames=("num_t", "z", "block_f",
@@ -259,158 +352,148 @@ def frontier_grid(W, mus, sigmas, extra=None, *, num_t: int = 1024,
     F, K = W.shape
     block_f = min(block_f, F)
     _check_block(F, K, block_f, dist_id, "fwd")
-    W = W.astype(jnp.float32)
-    mus = jnp.asarray(mus, jnp.float32)
-    per_row = mus.ndim == 2
-    mus2 = mus if per_row else mus[None, :]
-    sgs2 = jnp.asarray(sigmas, jnp.float32)
-    sgs2 = sgs2 if per_row else sgs2[None, :]
-    ex = _family_extra(dist_id, extra, K, F if per_row else None)
-    E = ex.shape[0]
-
-    kernel = functools.partial(_frontier_kernel, num_t=num_t, z=z, num_k=K,
+    operands, in_specs = _launch_operands(W, mus, sigmas, extra, dist_id,
+                                          block_f)
+    G = F // block_f
+    out_specs, out_shape = _moment_outputs(G, block_f)
+    kernel = functools.partial(_frontier_kernel, num_t=num_t, z=z,
                                dist_id=dist_id)
-    return pl.pallas_call(
+    mu, var = pl.pallas_call(
         kernel,
-        grid=(F // block_f,),
-        in_specs=[
-            pl.BlockSpec((block_f, K), lambda i: (i, 0)),
-        ] + _stat_specs(F, K, E, block_f, per_row),
-        out_specs=[
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((F,), jnp.float32),
-                   jax.ShapeDtypeStruct((F,), jnp.float32)],
+        grid=(G,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(W, mus2, sgs2, ex)
+        name=f"frontier_grid_fwd_{dist_id}",
+    )(*operands)
+    return mu.reshape(F), var.reshape(F)
 
 
 def _frontier_grad_kernel(w_ref, mu_ref, sg_ref, ex_ref,
                           mu_out_ref, var_out_ref, dmu_out_ref, dvar_out_ref,
-                          *param_out_refs, num_t: int, z: float, num_k: int,
-                          dist_id: str, param_grads: bool):
+                          *rest, num_t: int, z: float, dist_id: str,
+                          param_grads: bool):
     """Fused forward + analytic adjoint (see module docstring for the math).
 
     Pass 1 is the forward K-loop building the joint log-CDF; pass 2 streams K
-    again, turning the shared (bf, T) joint-CDF tile into the per-channel
-    P*/Pv* accumulator pairs — one per live feature in
+    again, turning the shared (T, bf) joint-CDF tile into the per-channel
+    P*/Pv* accumulator rows — one pair per live feature in
     ``distributions.family_features(dist_id, param_grads)``, so unused
-    accumulators never exist in the compiled program. Grad accumulators live
-    in the same VMEM tile as the forward state — no (F, T, K) residuals ever
-    leave the program. With ``param_grads`` the same two passes additionally
-    emit the mus/sigmas/extra-row-0 adjoints (six more (bf, K) outputs):
-    the parameter cotangents contract the SAME accumulators against
-    different per-channel constants, so full-parameter mode costs extra
-    epilogue arithmetic and output tiles, not a third K-loop.
+    accumulators never exist in the compiled program. The accumulators are
+    (K, bf) VMEM scratch written one channel row per step — no (F, T, K)
+    residuals ever leave the program. The epilogue then walks K twice in
+    8-row chunks: once for the moving-grid sums over channels, once to
+    contract the accumulators into the output rows. With ``param_grads``
+    the same passes additionally emit the mus/sigmas/extra-row-0 adjoints
+    (six more (K, bf) outputs): the parameter cotangents contract the SAME
+    accumulators against different per-channel constants, so
+    full-parameter mode costs extra epilogue arithmetic and output tiles,
+    not a third K-loop.
     """
-    w = w_ref[...]            # (bf, K)
-    mus = mu_ref[...]         # (1, K) shared | (bf, K) per-row
-    sgs = sg_ref[...]         # (1, K) shared | (bf, K) per-row
-    ex = ex_ref[...]          # (E, K) shared | (E, bf, K) per-row
-    means_eff, stds_eff = dists.family_effective_moments(dist_id, w, mus, sgs, ex)
-    reach = means_eff + z * stds_eff
-
-    amax = jnp.max(reach, axis=-1, keepdims=True)            # (bf, 1)
-    tmax = jnp.maximum(amax, 1e-12)
-    frac = jax.lax.broadcasted_iota(jnp.float32, (1, num_t), 1) / (num_t - 1)
-    ts = tmax * frac          # (bf, T)
-
-    def add_channel(kk, logF):
-        cdf = dists.family_cdf(dist_id, ts, _slice_k(w, kk), _slice_k(mus, kk),
-                               _slice_k(sgs, kk), _slice_k(ex, kk))
-        return logF + jnp.log(jnp.clip(cdf, _CDF_FLOOR, 1.0))
-
-    logF = jax.lax.fori_loop(0, num_k, add_channel, jnp.zeros_like(ts))
-    F_t = jnp.exp(logF)
+    n_param_outs = 6 if param_grads else 0
+    out_refs = (dmu_out_ref, dvar_out_ref) + tuple(rest[:n_param_outs])
+    reach_ref, *acc_refs = rest[n_param_outs:]
+    refs = (w_ref, mu_ref, sg_ref, ex_ref)
+    amax, tmax, ts, wq = _prologue(refs, num_t, z, dist_id, reach_ref)
+    F_t = jnp.exp(_log_joint_cdf(refs, ts, dist_id))
     surv = 1.0 - F_t
 
-    dt = tmax[:, 0] / (num_t - 1)  # (bf,)
-    mu = (jnp.sum(surv, -1) - 0.5 * (surv[:, 0] + surv[:, -1])) * dt
-    tsurv = ts * surv
-    m2 = 2.0 * (jnp.sum(tsurv, -1) - 0.5 * (tsurv[:, 0] + tsurv[:, -1])) * dt
+    dt = tmax / (num_t - 1)                                   # (1, bf)
+    mu = jnp.sum(wq * surv, axis=0, keepdims=True) * dt
+    m2 = 2.0 * jnp.sum(wq * ts * surv, axis=0, keepdims=True) * dt
     var_raw = m2 - mu * mu
     mu_out_ref[...] = mu
     var_out_ref[...] = jnp.maximum(var_raw, 0.0)
 
     # pass 2: per-channel accumulators off the shared F(t) tile. wF folds the
     # trapezoid weights into the joint CDF once.
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, num_t), 1)
-    wq = jnp.where((idx == 0) | (idx == num_t - 1), 0.5, 1.0)
-    wF = wq * F_t                                            # (bf, T)
-    tmu = ts - mu[:, None]                                   # (bf, T)
-    use_1, use_t, use_z = dists.family_features(dist_id, params=param_grads)
+    wF = wq * F_t                                             # (T, bf)
+    tmu = ts - mu                                             # (T, bf)
+    feats = [f for f, use in zip("1tz", dists.family_features(
+        dist_id, params=param_grads)) if use]
 
     def grad_channel(kk, carry):
+        sl = pl.ds(kk, 1)
         cdf_raw, D, ok, zsc = dists.family_adjoint_parts(
-            dist_id, ts, _slice_k(w, kk), _slice_k(mus, kk),
-            _slice_k(sgs, kk), _slice_k(ex, kk))
+            dist_id, ts, *_channel_rows(refs, sl))
         Cc = jnp.clip(cdf_raw, _CDF_FLOOR, 1.0)
         gate = jnp.where(cdf_raw >= 1.0, 0.5, 1.0) * (cdf_raw > _CDF_FLOOR) * ok
-        a = wF * (gate * D / Cc)                             # (bf, T)
-        updates = []
-        if use_1:
-            updates.append(jnp.sum(a, -1, keepdims=True))            # P0
-            updates.append(jnp.sum(a * tmu, -1, keepdims=True))      # Pv0
-        if use_t:
-            updates.append(jnp.sum(a * ts, -1, keepdims=True))       # P1
-            updates.append(jnp.sum(a * ts * tmu, -1, keepdims=True))  # Pv1
-        if use_z:
-            updates.append(jnp.sum(a * zsc, -1, keepdims=True))      # Pz
-            updates.append(jnp.sum(a * zsc * tmu, -1, keepdims=True))  # Pvz
-        return tuple(jax.lax.dynamic_update_slice_in_dim(acc, upd, kk, axis=1)
-                     for acc, upd in zip(carry, updates))
+        a = wF * (gate * D / Cc)                              # (T, bf)
+        basis = {"1": a, "t": a * ts, "z": a * zsc}
+        for i, f in enumerate(feats):
+            acc_refs[2 * i][sl, :] = jnp.sum(basis[f], axis=0, keepdims=True)
+            acc_refs[2 * i + 1][sl, :] = jnp.sum(basis[f] * tmu, axis=0,
+                                                 keepdims=True)
+        return carry
 
-    zeros_fk = jnp.zeros_like(w)
-    n_acc = 2 * (int(use_1) + int(use_t) + int(use_z))
-    accs = list(jax.lax.fori_loop(0, num_k, grad_channel,
-                                  (zeros_fk,) * n_acc))
-    P0, Pv0 = (accs.pop(0), accs.pop(0)) if use_1 else (0.0, 0.0)
-    P1, Pv1 = (accs.pop(0), accs.pop(0)) if use_t else (0.0, 0.0)
-    Pz, Pvz = (accs.pop(0), accs.pop(0)) if use_z else (0.0, 0.0)
+    jax.lax.fori_loop(0, w_ref.shape[0], grad_channel, None)
+
+    def accs(sl):
+        """{feature: (P_f, Pv_f)} rows; absent features contract as 0."""
+        P = dict.fromkeys("1tz", (0.0, 0.0))
+        for i, f in enumerate(feats):
+            P[f] = (acc_refs[2 * i][sl, :], acc_refs[2 * i + 1][sl, :])
+        return P
 
     # epilogue: combine fixed-grid and moving-grid (tmax) terms with the
     # family's per-channel constants — module docstring "Differentiating the
     # family-parametric survival integral"
-    alpha, beta, gamma0, gamma1 = dists.family_coeffs(dist_id, w, mus, sgs, ex)
-    dtc = dt[:, None]
-    tmx = tmax[:, 0]
-    b_mu = (mu - dt * jnp.sum(gamma0 * P0 + gamma1 * P1, -1)) / tmx
-    b_var = 2.0 * (var_raw
-                   - dt * jnp.sum(gamma0 * Pv0 + gamma1 * Pv1, -1)) / tmx
-    ind = (reach == amax).astype(jnp.float32)
-    tie = (ind / jnp.sum(ind, -1, keepdims=True)
-           * (amax > 1e-12).astype(jnp.float32))
-    var_pos = (var_raw > 0.0)[:, None]
+    def grid_sums(sl, carry):
+        s_mu, s_var, n_tie = carry
+        _, _, gamma0, gamma1 = dists.family_coeffs(
+            dist_id, *_channel_rows(refs, sl))
+        P = accs(sl)
+        s_mu = s_mu + jnp.sum(gamma0 * P["1"][0] + gamma1 * P["t"][0],
+                              axis=0, keepdims=True)
+        s_var = s_var + jnp.sum(gamma0 * P["1"][1] + gamma1 * P["t"][1],
+                                axis=0, keepdims=True)
+        n_tie = n_tie + jnp.sum((reach_ref[sl, :] == amax).astype(jnp.float32),
+                                axis=0, keepdims=True)
+        return s_mu, s_var, n_tie
 
-    def contract(c1, ct, cz, dreach):
-        gvec = dreach * tie
-        dmu_th = (-dtc * (c1 * P0 + ct * P1 + cz * Pz)
-                  + b_mu[:, None] * gvec)
-        dvar_th = jnp.where(
-            var_pos,
-            -2.0 * dtc * (c1 * Pv0 + ct * Pv1 + cz * Pvz)
-            + b_var[:, None] * gvec, 0.0)
-        return dmu_th, dvar_th
+    zero_row = jnp.zeros_like(amax)
+    s_mu, s_var, n_tie = _fold_channels(w_ref.shape[0], grid_sums,
+                                        (zero_row,) * 3)
+    b_mu = (mu - dt * s_mu) / tmax
+    b_var = 2.0 * (var_raw - dt * s_var) / tmax
+    # ties split the tmax cotangent evenly (n_tie >= 1: amax is one of them)
+    tie_w = (amax > 1e-12).astype(jnp.float32) / n_tie
+    var_pos = var_raw > 0.0
 
-    dreach_w = dists.family_dreach(dist_id, w, mus, sgs, ex, z)
-    dmu, dvar = contract(alpha, beta, zeros_fk, dreach_w)
-    dmu_out_ref[...] = dmu
-    dvar_out_ref[...] = dvar
-    if not param_grads:
-        return
-    (dmuM_ref, dvarM_ref, dmuS_ref, dvarS_ref, dmuE_ref, dvarE_ref) = \
-        param_out_refs
-    c_mu, c_sigma, c_rho = dists.family_param_coeffs(dist_id, w, mus, sgs, ex)
-    dr_mu, dr_sigma, dr_rho = dists.family_dreach_params(
-        dist_id, w, mus, sgs, ex, z)
-    dmuM_ref[...], dvarM_ref[...] = contract(*c_mu, dr_mu)
-    dmuS_ref[...], dvarS_ref[...] = contract(*c_sigma, dr_sigma)
-    if dists.family_has_extra_grads(dist_id):
-        dmuE_ref[...], dvarE_ref[...] = contract(*c_rho, dr_rho)
-    else:
-        dmuE_ref[...] = zeros_fk
-        dvarE_ref[...] = zeros_fk
+    def emit(sl, carry):
+        rows = _channel_rows(refs, sl)
+        P = accs(sl)
+        tie = (reach_ref[sl, :] == amax).astype(jnp.float32) * tie_w
+
+        def contract(c1, ct, cz, dreach):
+            gvec = dreach * tie
+            dmu_th = (-dt * (c1 * P["1"][0] + ct * P["t"][0] + cz * P["z"][0])
+                      + b_mu * gvec)
+            dvar_th = jnp.where(
+                var_pos,
+                -2.0 * dt * (c1 * P["1"][1] + ct * P["t"][1]
+                             + cz * P["z"][1])
+                + b_var * gvec, 0.0)
+            return dmu_th, dvar_th
+
+        alpha, beta, _, _ = dists.family_coeffs(dist_id, *rows)
+        grads = [contract(alpha, beta, 0.0,
+                          dists.family_dreach(dist_id, *rows, z))]
+        if param_grads:
+            c_mu, c_sigma, c_rho = dists.family_param_coeffs(dist_id, *rows)
+            dr_mu, dr_sigma, dr_rho = dists.family_dreach_params(
+                dist_id, *rows, z)
+            grads += [contract(*c_mu, dr_mu), contract(*c_sigma, dr_sigma),
+                      contract(*c_rho, dr_rho)
+                      if dists.family_has_extra_grads(dist_id)
+                      else (0.0, 0.0)]
+        for ref, g in zip(out_refs, (g for pair in grads for g in pair)):
+            ref[sl, :] = jnp.broadcast_to(g, tie.shape)
+        return carry
+
+    _fold_channels(w_ref.shape[0], emit, None)
 
 
 @functools.partial(jax.jit, static_argnames=("num_t", "z", "block_f",
@@ -439,31 +522,31 @@ def frontier_grid_with_grads(W, mus, sigmas, extra=None, *, num_t: int = 1024,
     F, K = W.shape
     block_f = min(block_f, F)
     _check_block(F, K, block_f, dist_id, "pgrad" if param_grads else "grad")
-    W = W.astype(jnp.float32)
-    mus = jnp.asarray(mus, jnp.float32)
-    per_row = mus.ndim == 2
-    mus2 = mus if per_row else mus[None, :]
-    sgs2 = jnp.asarray(sigmas, jnp.float32)
-    sgs2 = sgs2 if per_row else sgs2[None, :]
-    ex = _family_extra(dist_id, extra, K, F if per_row else None)
-    E = ex.shape[0]
-
-    kernel = functools.partial(_frontier_grad_kernel, num_t=num_t, z=z,
-                               num_k=K, dist_id=dist_id,
-                               param_grads=param_grads)
+    operands, in_specs = _launch_operands(W, mus, sigmas, extra, dist_id,
+                                          block_f)
+    G = F // block_f
+    out_specs, out_shape = _moment_outputs(G, block_f)
     n_fk_outs = 8 if param_grads else 2
-    return pl.pallas_call(
+    out_specs += [pl.BlockSpec((None, K, block_f), lambda i: (i, 0, 0))
+                  ] * n_fk_outs
+    out_shape += [jax.ShapeDtypeStruct((G, K, block_f), jnp.float32)
+                  ] * n_fk_outs
+    # the reach rows plus one (P_f, Pv_f) accumulator pair per live feature
+    n_acc = 2 * sum(dists.family_features(dist_id, params=param_grads))
+    scratch = [pltpu.VMEM((K, block_f), jnp.float32)] * (1 + n_acc)
+    kernel = functools.partial(_frontier_grad_kernel, num_t=num_t, z=z,
+                               dist_id=dist_id, param_grads=param_grads)
+    mode = "pgrad" if param_grads else "grad"
+    mu, var, *fk = pl.pallas_call(
         kernel,
-        grid=(F // block_f,),
-        in_specs=[
-            pl.BlockSpec((block_f, K), lambda i: (i, 0)),
-        ] + _stat_specs(F, K, E, block_f, per_row),
-        out_specs=[
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-        ] + [pl.BlockSpec((block_f, K), lambda i: (i, 0))] * n_fk_outs,
-        out_shape=[jax.ShapeDtypeStruct((F,), jnp.float32),
-                   jax.ShapeDtypeStruct((F,), jnp.float32)]
-        + [jax.ShapeDtypeStruct((F, K), jnp.float32)] * n_fk_outs,
+        grid=(G,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(W, mus2, sgs2, ex)
+        name=f"frontier_grid_{mode}_{dist_id}",
+    )(*operands)
+    return (mu.reshape(F), var.reshape(F)) + tuple(
+        jnp.swapaxes(o, 1, 2).reshape(F, K) for o in fk)

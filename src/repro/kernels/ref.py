@@ -129,8 +129,9 @@ def frontier_grid_with_grads_ref(W, mus, sigmas, num_t: int = 1024,
 
     * ``jnp.clip(cdf, floor, 1)`` passes gradient 1 strictly inside the
       bounds, 0.5 at a saturated bound (f32 CDF hits exactly 1.0 for
-      z >= ~5.3), and 0 outside. The f32 cancellation in ``0.5*(1+erf)``
-      means the lower clip only ever activates at cdf == 0, never at a tie.
+      z >= ~5.3), and 0 outside. The lower clip activates only where the
+      CDF falls below the floor (z < ~-12.7, ``dists.Phi`` keeps relative
+      accuracy down there); an exact tie with the floor is measure-zero.
     * ``jnp.max`` over channels splits the tmax cotangent evenly over ties.
     * degenerate (point-mass) channels take the non-differentiable branch, so
       their direct gradient is 0 — they still receive the grid-path gradient

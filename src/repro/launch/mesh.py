@@ -13,19 +13,13 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; 0.4.x predates them
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "batch_axes"]
 
 
 def _make_mesh(shape, axes):
-    """make_mesh with Auto axis types when the installed jax supports them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """make_mesh with Auto axis types on every axis."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

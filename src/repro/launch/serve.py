@@ -16,6 +16,7 @@ import argparse
 import jax
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import ARCHS, get_config
 from ..models import build_model
 from ..obs import trace as obs
@@ -98,6 +99,7 @@ def _export_trace(prefix: str) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="smollm-360m")
     ap.add_argument("--tiny", action="store_true")

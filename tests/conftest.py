@@ -4,5 +4,3 @@ import sys
 # tests must see ONE device (the dry-run sets 512 for itself in-process)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-# make the _hypothesis_fallback shim importable from test modules
-sys.path.insert(0, os.path.dirname(__file__))

@@ -295,15 +295,15 @@ _PALLAS_WRAPPER = """
 
 class TestVmemAudit:
     def test_rpa030_fires_on_pgrad_overflow(self, tmp_path):
-        # 256 overflows the 12 MiB budget for EVERY grad/pgrad family combo
-        # at the K=1024/T=1024 audit point — the acceptance-criteria case
+        # 256 overflows the VMEM budget for the empirical pgrad combo at the
+        # K=1024/T=1024 audit point — the acceptance-criteria case
         src = _PALLAS_WRAPPER.format(
             block_f=256, extra_param=", param_grads: bool = False",
             guard="if F % block_f:\n            raise ValueError(F)")
         fs = _lint(tmp_path, src)
         assert "RPA030" in _codes(fs)
         msg = next(f for f in fs if f.code == "RPA030").message
-        assert "pgrad" in msg and "64" in msg  # largest safe fused block
+        assert "pgrad" in msg and "128" in msg  # largest safe fused block
 
     def test_rpa030_silent_on_safe_fwd_default(self, tmp_path):
         src = _PALLAS_WRAPPER.format(
@@ -323,15 +323,13 @@ class TestVmemAudit:
         from repro.kernels import autotune
 
         for dist_id in FAMILIES:
-            for stacked in (False, True):
-                assert autotune.vmem_bytes(128, 1024, 1024, fused=False,
-                                           dist_id=dist_id, stacked=stacked) \
+            assert autotune.vmem_bytes(128, 1024, 1024, fused=False,
+                                       dist_id=dist_id) \
+                <= autotune._VMEM_BUDGET_BYTES
+            for params in (False, True):
+                assert autotune.vmem_bytes(64, 1024, 1024, fused=True,
+                                           dist_id=dist_id, params=params) \
                     <= autotune._VMEM_BUDGET_BYTES
-                for params in (False, True):
-                    assert autotune.vmem_bytes(64, 1024, 1024, fused=True,
-                                               dist_id=dist_id, params=params,
-                                               stacked=stacked) \
-                        <= autotune._VMEM_BUDGET_BYTES
 
 
 class TestContracts:

@@ -5,10 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # degrade to fixed-seed sweeps (see requirements-dev.txt)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     clark_max_moments_2, clark_max_moments_seq, equal_split,

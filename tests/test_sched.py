@@ -1,10 +1,7 @@
 """Scheduler integration: balancer, straggler policy, elasticity, simulator."""
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # degrade to fixed-seed sweeps (see requirements-dev.txt)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.sched import StragglerPolicy, UncertaintyAwareBalancer, integerize
 from repro.sim import Channel, ClusterSim
