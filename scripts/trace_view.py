@@ -5,14 +5,12 @@ Usage (from the repo root)::
 
     python scripts/trace_view.py TRACE_serve_trace_smoke.jsonl
     python scripts/trace_view.py trace.jsonl -o trace.perfetto.json
-    python scripts/trace_view.py trace.jsonl --prometheus
 
 Reads a ``repro.obs`` JSONL trace (one record per line, as written by
 ``repro.obs.export.write_jsonl`` / the serve CLI's ``--trace``), validates
 every record against the event schema, writes the Chrome trace_event file
 Perfetto and chrome://tracing load directly, and prints a per-name summary
 table (count, total/mean duration for spans; count per audit event type).
-``--prometheus`` additionally prints the text-format metrics snapshot.
 """
 import argparse
 import os
@@ -50,8 +48,6 @@ def main(argv=None) -> int:
     ap.add_argument("-o", "--out", default=None,
                     help="Perfetto output path (default: "
                          "<input stem>.perfetto.json)")
-    ap.add_argument("--prometheus", action="store_true",
-                    help="also print the Prometheus text-format snapshot")
     args = ap.parse_args(argv)
 
     records = obs_export.read_jsonl(args.jsonl)
@@ -64,8 +60,6 @@ def main(argv=None) -> int:
     print(f"{args.jsonl}: {n} records, {len(kinds)} span kinds, "
           f"{len(types)} audit event types -> {out}")
     print(summarize(records))
-    if args.prometheus:
-        print(obs_export.prometheus_snapshot(records))
     return 0
 
 

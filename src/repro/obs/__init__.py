@@ -1,14 +1,12 @@
 """Cross-layer tracing + decision-audit subsystem (PR 10).
 
-Spans (how long), audit events (why), and exporters (JSONL / Perfetto /
-Prometheus) for the whole stack — solver ladder phases, stacked kernel
-launches, engine tick stages, balancer refreshes, sim steps — under a
-hard zero-perturbation contract: no RNG draws, no jit-cache-key effects,
-no trace state in any checkpoint. ``REPRO_TRACE=1`` turns recording on;
-off is a no-op fast path. See docs/OBSERVABILITY.md.
-
-``repro.obs.export`` is imported on demand (not here) so the serving tier
-can import ``repro.obs`` without a cycle through ``repro.serve``.
+Spans (how long), audit events (why), and exporters (JSONL / Perfetto)
+for the whole stack — solver ladder phases and device waits, stacked
+kernel launches, engine tick stages, balancer refreshes, sim steps —
+under a hard zero-perturbation contract: no RNG draws, no jit-cache-key
+effects, no trace state in any checkpoint. ``REPRO_TRACE=1`` turns
+recording on; off is a no-op fast path. Recorded spans are also profiler
+annotations (``repro.obs.trace``). See docs/OBSERVABILITY.md.
 """
 from . import events, names  # noqa: F401
 from .trace import (TRACER, Tracer, capture, clear, current_tick,  # noqa: F401

@@ -1,4 +1,4 @@
-"""Exporters for trace records: JSONL, Chrome/Perfetto, Prometheus text.
+"""Exporters for trace records: JSONL and Chrome/Perfetto.
 
 Record schema (one dict per span/event, produced by
 :mod:`repro.obs.trace`):
@@ -12,22 +12,19 @@ Record schema (one dict per span/event, produced by
 ``validate_records`` is the schema gate CI's trace tier runs over the
 exported JSONL; ``to_perfetto`` emits the Chrome ``trace_event`` JSON that
 chrome://tracing and https://ui.perfetto.dev load directly (complete
-``"X"`` events for spans, instant ``"i"`` events for the audit log);
-``prometheus_snapshot`` folds the same records into counter/summary text
-built on :class:`repro.serve.telemetry.StreamingStat`.
+``"X"`` events for spans, instant ``"i"`` events for the audit log).
 """
 from __future__ import annotations
 
 import json
 from collections import defaultdict
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Iterable, List
 
-from . import names, trace
+from . import names
 
 __all__ = [
     "write_jsonl", "read_jsonl", "validate_records", "to_perfetto",
-    "write_perfetto", "prometheus_snapshot", "phase_totals",
-    "span_kinds", "event_types",
+    "write_perfetto", "phase_totals", "span_kinds", "event_types",
 ]
 
 _COMMON_KEYS = {"type", "name", "ts_us", "tick", "tid", "seq", "attrs"}
@@ -133,68 +130,6 @@ def write_perfetto(records: Iterable[Dict[str, Any]], path: str,
     with open(path, "w") as f:
         json.dump(doc, f)
     return len(doc["traceEvents"])
-
-
-# ---------------------------------------------------------------- Prometheus
-def prometheus_snapshot(records: Iterable[Dict[str, Any]],
-                        dropped: Optional[int] = None) -> str:
-    """Counters + duration summaries in Prometheus text exposition format.
-
-    Built on the serving tier's :class:`StreamingStat` so span-duration
-    quantiles come from the same reservoir estimator the engine telemetry
-    already trusts. These stats are constructed fresh per snapshot with
-    their own seeded RNG — nothing here touches a checkpointed stream.
-    """
-    from ..serve.telemetry import StreamingStat  # deferred: avoid cycle
-
-    span_stats: Dict[str, Any] = {}
-    event_counts: Dict[str, int] = defaultdict(int)
-    for rec in records:
-        if rec["type"] == "span":
-            st = span_stats.get(rec["name"])
-            if st is None:
-                st = span_stats[rec["name"]] = StreamingStat()
-            st.add(rec["dur_us"])
-        else:
-            event_counts[rec["name"]] += 1
-
-    lines = [
-        f"# HELP {names.METRIC_SPAN_COUNT} spans recorded per kind",
-        f"# TYPE {names.METRIC_SPAN_COUNT} counter",
-    ]
-    for name in sorted(span_stats):
-        st = span_stats[name].summary()
-        lines.append(f'{names.METRIC_SPAN_COUNT}{{kind="{name}"}} '
-                     f'{st["count"]}')
-    lines += [
-        f"# HELP {names.METRIC_SPAN_US} span duration microseconds",
-        f"# TYPE {names.METRIC_SPAN_US} summary",
-    ]
-    for name in sorted(span_stats):
-        st = span_stats[name].summary()
-        for q in ("p50", "p90", "p99"):
-            lines.append(
-                f'{names.METRIC_SPAN_US}{{kind="{name}",quantile='
-                f'"0.{q[1:]}"}} {st[q]:.3f}')
-        lines.append(f'{names.METRIC_SPAN_US}_sum{{kind="{name}"}} '
-                     f'{st["mean"] * st["count"]:.3f}')
-        lines.append(f'{names.METRIC_SPAN_US}_count{{kind="{name}"}} '
-                     f'{st["count"]}')
-    lines += [
-        f"# HELP {names.METRIC_EVENT_COUNT} audit events per type",
-        f"# TYPE {names.METRIC_EVENT_COUNT} counter",
-    ]
-    for name in sorted(event_counts):
-        lines.append(f'{names.METRIC_EVENT_COUNT}{{type="{name}"}} '
-                     f'{event_counts[name]}')
-    if dropped is None:
-        dropped = trace.dropped()
-    lines += [
-        f"# HELP {names.METRIC_DROPPED} records dropped by the ring buffer",
-        f"# TYPE {names.METRIC_DROPPED} counter",
-        f"{names.METRIC_DROPPED} {dropped}",
-    ]
-    return "\n".join(lines) + "\n"
 
 
 # ------------------------------------------------------------- aggregations

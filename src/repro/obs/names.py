@@ -1,4 +1,4 @@
-"""Central registry of every span kind, audit-event type and metric name.
+"""Central registry of every span kind and audit-event type.
 
 Every ``obs.span`` / ``obs.timed_span`` / ``obs.event`` emit site MUST name
 its record with a constant from this module — never a free string literal.
@@ -8,9 +8,8 @@ dashboard reading ``solver.phase`` can never silently diverge from an emit
 site that renamed itself ``solve.phase``.
 
 Naming convention: ``<layer>.<thing>`` for spans, ``audit.<decision>`` for
-events, ``repro_<snake>`` for Prometheus metric names. Attribute keys ride
-free-form on each record (they are schema-checked per event type in
-:mod:`repro.obs.export`, not here).
+events. Attribute keys ride free-form on each record (they are
+schema-checked per event type in :mod:`repro.obs.export`, not here).
 """
 from __future__ import annotations
 
@@ -18,6 +17,10 @@ from __future__ import annotations
 # Solver ladder phases inside workflow.solve.solve_dag (attr ``phase`` is one
 # of starts/presolve/triage/refine/final_score/fragility).
 SPAN_SOLVER_PHASE = "solver.phase"
+# solve_dag blocking on the device (a block_until_ready or a read-back),
+# inside the solver.phase span of the same ``phase`` attr; a phase less its
+# waits is the host's own work.
+SPAN_SOLVER_WAIT = "solver.wait"
 # One stacked PGD solve over the rows of a family group
 # (serve.engine.row_pgd_step); attrs family, rows, K, num_t.
 SPAN_SOLVER_PGD = "solver.pgd"
@@ -36,7 +39,7 @@ SPAN_SIM_STEP = "sim.step"
 SPAN_CHAOS_CYCLE = "chaos.cycle"
 
 SPAN_KINDS = frozenset({
-    SPAN_SOLVER_PHASE, SPAN_SOLVER_PGD, SPAN_KERNEL_LAUNCH,
+    SPAN_SOLVER_PHASE, SPAN_SOLVER_WAIT, SPAN_SOLVER_PGD, SPAN_KERNEL_LAUNCH,
     SPAN_ENGINE_TICK, SPAN_ENGINE_STAGE, SPAN_SCHED_REFRESH,
     SPAN_SIM_STEP, SPAN_CHAOS_CYCLE,
 })
@@ -72,14 +75,3 @@ EVENT_TYPES = frozenset({
 })
 
 ALL_NAMES = SPAN_KINDS | EVENT_TYPES
-
-# ------------------------------------------------------------------- metrics
-# Prometheus-style snapshot names (repro.obs.export.prometheus_snapshot).
-METRIC_SPAN_COUNT = "repro_span_count"
-METRIC_SPAN_US = "repro_span_duration_us"
-METRIC_EVENT_COUNT = "repro_audit_event_count"
-METRIC_DROPPED = "repro_trace_dropped_records"
-
-METRIC_NAMES = frozenset({
-    METRIC_SPAN_COUNT, METRIC_SPAN_US, METRIC_EVENT_COUNT, METRIC_DROPPED,
-})

@@ -23,6 +23,13 @@ timer the caller already paid) but records only when tracing is on; that
 is what makes spans the single timing source of truth for profiles like
 ``solve_dag``'s ``phase_us`` without forcing tracing on for benchmarks.
 
+A span that records also holds a ``jax.profiler.TraceAnnotation`` for its
+extent, labelled :func:`label` (``name:attr``, e.g.
+``solver.phase:presolve``), so a profiler trace shows every recorded span
+on its host plane, on the profiler's own clock, beside the device's
+operations. jax is imported on the first recorded span; a span that does
+not record never touches the profiler.
+
 Records are plain dicts (schema in docs/OBSERVABILITY.md, validated by
 :func:`repro.obs.export.validate_records`); the ring buffer drops the
 oldest records past ``capacity`` and counts the drops.
@@ -40,9 +47,9 @@ from typing import Any, Dict, Iterator, List, Optional
 from . import names
 
 __all__ = [
-    "ENV_VAR", "Tracer", "TRACER", "enabled", "set_enabled", "span",
-    "timed_span", "event", "traced", "set_tick", "current_tick", "mark",
-    "records", "dropped", "clear", "capture",
+    "ENV_VAR", "Tracer", "TRACER", "enabled", "set_enabled", "label",
+    "span", "timed_span", "event", "traced", "set_tick", "current_tick",
+    "mark", "records", "dropped", "clear", "capture",
 ]
 
 ENV_VAR = "REPRO_TRACE"
@@ -51,6 +58,28 @@ _DEFAULT_CAPACITY = 1 << 16
 
 def _now_us() -> float:
     return time.perf_counter_ns() / 1000.0
+
+
+# the attribute that names a span's part, in order of precedence
+_LABEL_ATTRS = ("stage", "phase", "mode")
+
+
+def label(name: str, attrs: Dict[str, Any]) -> str:
+    """A span's profiler label: ``name:<first of stage/phase/mode>``."""
+    for key in _LABEL_ATTRS:
+        if key in attrs:
+            return f"{name}:{attrs[key]}"
+    return name
+
+
+def _annotation(text: str):
+    """An entered ``jax.profiler.TraceAnnotation``; jax is imported here, so
+    a process that records no span never imports it."""
+    import jax.profiler
+
+    ann = jax.profiler.TraceAnnotation(text)
+    ann.__enter__()
+    return ann
 
 
 class _NoopSpan:
@@ -70,9 +99,11 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    """Context manager measuring one span; records on exit when asked."""
+    """Context manager measuring one span; records on exit when asked, and
+    then also annotates its extent in the profiler trace."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_record", "_t0_ns", "dur_us")
+    __slots__ = ("_tracer", "name", "attrs", "_record", "_t0_ns", "dur_us",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
                  record: bool):
@@ -82,8 +113,11 @@ class _Span:
         self._record = record
         self._t0_ns = 0
         self.dur_us = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        if self._record:
+            self._ann = _annotation(label(self.name, self.attrs))
         self._t0_ns = time.perf_counter_ns()
         return self
 
@@ -91,6 +125,7 @@ class _Span:
         t1 = time.perf_counter_ns()
         self.dur_us = (t1 - self._t0_ns) / 1000.0
         if self._record:
+            self._ann.__exit__(None, None, None)
             self._tracer._emit({
                 "type": "span",
                 "name": self.name,
@@ -167,8 +202,16 @@ class Tracer:
             return self._seq
 
     def records(self, since: int = 0) -> List[Dict[str, Any]]:
+        # seq rises along the buffer: walk back from the newest record, so
+        # a reader that drains as it goes pays for the new records only
         with self._lock:
-            return [r for r in self._buf if r["seq"] > since]
+            out = []
+            for r in reversed(self._buf):
+                if r["seq"] <= since:
+                    break
+                out.append(r)
+        out.reverse()
+        return out
 
     def dropped(self) -> int:
         with self._lock:

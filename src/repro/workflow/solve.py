@@ -382,23 +382,28 @@ def _dag_fragility(structure, groups, stats, se_stacks, W, smu, svar,
     gmk = jax.vmap(jax.grad(
         lambda m, v: compose_structure(structure, m, v)[0],
         argnums=(0, 1)))(smu, svar)
-    g_mu, g_var = (np.asarray(g, np.float64) for g in gmk)   # (R, S)
+    with obs.span(obs_names.SPAN_SOLVER_WAIT, phase="fragility"):
+        g_mu, g_var = (np.asarray(g, np.float64) for g in gmk)   # (R, S)
     frag2 = np.zeros(R)
     for g, grp in enumerate(groups):
         idx = np.asarray(grp.idx)
         n = len(grp.idx)
         mus_g, sgs_g, ex_g = stats[g]
-        rows = np.asarray(W[:, idx, :]).reshape(R * n, kmax)
+        with obs.span(obs_names.SPAN_SOLVER_WAIT, phase="fragility"):
+            rows = np.asarray(W[:, idx, :]).reshape(R * n, kmax)
         outs = ops.frontier_moments_with_grads(
             rows, np.tile(np.asarray(mus_g), (R, 1)),
             np.tile(np.asarray(sgs_g), (R, 1)),
             num_t=num_t, impl=impl, block_f=bfs[g],
             family=(grp.dist_id, jnp.tile(jnp.asarray(ex_g), (1, R, 1))),
             param_grads=True)
-        dmu_m, dvar_m = (np.asarray(outs[4], np.float64).reshape(R, n, kmax),
-                         np.asarray(outs[5], np.float64).reshape(R, n, kmax))
-        dmu_s, dvar_s = (np.asarray(outs[6], np.float64).reshape(R, n, kmax),
-                         np.asarray(outs[7], np.float64).reshape(R, n, kmax))
+        with obs.span(obs_names.SPAN_SOLVER_WAIT, phase="fragility"):
+            dmu_m, dvar_m = (
+                np.asarray(outs[4], np.float64).reshape(R, n, kmax),
+                np.asarray(outs[5], np.float64).reshape(R, n, kmax))
+            dmu_s, dvar_s = (
+                np.asarray(outs[6], np.float64).reshape(R, n, kmax),
+                np.asarray(outs[7], np.float64).reshape(R, n, kmax))
         se_m, se_s = se_stacks[g]
         cm = g_mu[:, idx, None] * dmu_m + g_var[:, idx, None] * dvar_m
         cs = g_mu[:, idx, None] * dmu_s + g_var[:, idx, None] * dvar_s
@@ -479,6 +484,28 @@ def _starts(dag: StageDAG, mask: np.ndarray, kmax: int, restarts: int,
     return out.astype(np.float32)
 
 
+def _launches(phase: str, mode: str, groups, ks, n: int, launches: int,
+              kmax: int, num_t: int, bfs) -> List[dict]:
+    """``profile["launches"]`` entries of one rung, one per family group.
+
+    The rung ran ``launches`` kernel launches per group, each over the
+    group's stages for ``n`` candidates: ``rows`` real rows, padded to a
+    multiple of the launch's block (``rows_padded``, as ``ops`` pads them)
+    and every row to ``k`` channel slots, of which ``channels`` are real
+    (the stages' own widths), at ``num_t`` grid points.
+    """
+    out = []
+    for g, bf in zip(groups, bfs):
+        rows = n * len(g.idx)
+        bf = max(min(bf, rows), 1)
+        out.append({"phase": phase, "mode": mode, "family": g.dist_id,
+                    "launches": int(launches), "rows": rows,
+                    "rows_padded": -(-rows // bf) * bf, "block_f": bf,
+                    "k": kmax, "num_t": num_t,
+                    "channels": n * sum(ks[i] for i in g.idx)})
+    return out
+
+
 class _PhaseClock:
     """Sequential phase attribution on the span API (PR 10).
 
@@ -488,6 +515,9 @@ class _PhaseClock:
     measurement, not two hand timers drifting apart. ``timed_span`` always
     measures; it records into the trace ring buffer only under
     ``REPRO_TRACE=1``.
+
+    ``wait()`` spans a block on the device inside the open phase
+    (``solver.wait``, recorded only when tracing is on).
     """
 
     def __init__(self, phase_us: Dict[str, float]):
@@ -497,6 +527,10 @@ class _PhaseClock:
     def start(self, phase: str) -> None:
         self._open = obs.timed_span(obs_names.SPAN_SOLVER_PHASE,
                                     phase=phase).__enter__()
+
+    def wait(self):
+        return obs.span(obs_names.SPAN_SOLVER_WAIT,
+                        phase=self._open.attrs["phase"])
 
     def lap(self, next_phase: Optional[str] = None) -> None:
         sp = self._open
@@ -582,7 +616,9 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
 
     ``decision.profile`` carries per-phase wall times (``phase_us``) and
     solver counters (starts, survivors, pool size, steps run per phase) so
-    fidelity-ladder wins stay attributable.
+    fidelity-ladder wins stay attributable, and ``launches``: the kernel
+    work of each rung and family group (:func:`_launches`), counted on the
+    host from shapes.
     """
     phase_us: Dict[str, float] = {}
     clock = _PhaseClock(phase_us)
@@ -620,7 +656,8 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                 profile={"phase_us": {"final_score": round(sp.dur_us, 1)},
                          "noop": True, "starts": 0, "survivors": 0,
                          "pool": 1, "presolve_num_t": pnt,
-                         "eval_num_t": et})
+                         "eval_num_t": et,
+                         "launches": base.profile["launches"]})
         upd_np = np.array([1.0 if s.name in dset else 0.0
                            for s in dag.stages], np.float32)
 
@@ -680,7 +717,8 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     bfs_pre = tuple(_bf(g, R * len(g.idx), pnt, True, False) for g in groups)
     W1, _, _, n_pre = _run_phase(W0, bfs_pre, False, pre, pnt, patience,
                                  _PRESOLVE_LR, pre // 2)
-    jax.block_until_ready(W1)
+    with clock.wait():
+        jax.block_until_ready(W1)
     clock.lap("triage")
 
     # --- coarse triage: composed scores of {starts, presolve} at the same
@@ -691,10 +729,12 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                     for g in groups)
     c_mu, c_var, _, _ = _score_dag(dag.structure, dist_ids, idxs, stats,
                                    pool0, pnt, impl, bfs_tri)
-    csc = np.asarray(c_mu, np.float64) + lam_var * np.asarray(c_var,
-                                                              np.float64)
+    with clock.wait():
+        jax.block_until_ready((c_mu, c_var))
+        csc = np.asarray(c_mu, np.float64) + lam_var * np.asarray(
+            c_var, np.float64)
+        W0h, W1h = np.asarray(W0), np.asarray(W1)
     per_start = np.minimum(csc[:R], csc[R:])
-    W0h, W1h = np.asarray(W0), np.asarray(W1)
     Wch = np.where((csc[R:] <= csc[:R])[:, None, None], W1h, W0h)
     if prune_margin is None:
         keep = np.ones(R, bool)
@@ -728,7 +768,8 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                     for g in groups)
     Wf, Wb, _, n_ref = _run_phase(Wr0, bfs_ref, True, steps, num_t, patience,
                                   _REFINE_LR, steps // 2)
-    jax.block_until_ready(Wf)
+    with clock.wait():
+        jax.block_until_ready(Wf)
     clock.lap("final_score")
 
     # --- final pick at evaluation fidelity: refine inits (which include the
@@ -739,9 +780,12 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                      for g in groups)
     mk_mu, mk_var, smu, svar = _score_dag(dag.structure, dist_ids, idxs,
                                           stats, cands, et, impl, bfs_eval)
-    score = np.asarray(mk_mu, np.float64) + lam_var * np.asarray(
-        mk_var, np.float64)
-    clock.lap("fragility" if posteriors is not None else None)
+    with clock.wait():
+        jax.block_until_ready((mk_mu, mk_var))
+        score = np.asarray(mk_mu, np.float64) + lam_var * np.asarray(
+            mk_var, np.float64)
+    if posteriors is not None:
+        clock.lap("fragility")
 
     method = ("pgd-dag-joint-inc" if upd_np is not None else "pgd-dag-joint")
     frag = None
@@ -769,20 +813,37 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                             cands[best:best + 1], smu[best:best + 1],
                             svar[best:best + 1], num_t, impl, bfs_frag)
         frag_best = float(fb[0])
-    if posteriors is not None:
-        clock.lap()
+    # the winner's read-backs close the last phase: final_score, or
+    # fragility where posteriors were given
+    with clock.wait():
+        Wbest = np.asarray(cands[best], np.float64)
+        mk_best, var_best = float(mk_mu[best]), float(mk_var[best])
+        smu_best = np.asarray(smu[best], np.float64)
+        svar_best = np.asarray(svar[best], np.float64)
+        n_pre, n_ref = int(n_pre), int(n_ref)
+    clock.lap()
 
-    Wbest = np.asarray(cands[best], np.float64)
     weights = {s.name: Wbest[i, :s.k] for i, s in enumerate(dag.stages)}
+    ks = [s.k for s in dag.stages]
+    launches = (
+        _launches("presolve", "grad", groups, ks, R, n_pre, kmax, pnt,
+                  bfs_pre)
+        + _launches("triage", "fwd", groups, ks, 2 * R, 1, kmax, pnt, bfs_tri)
+        + _launches("refine", "grad", groups, ks, survivors, n_ref, kmax,
+                    num_t, bfs_ref)
+        + _launches("final_score", "fwd", groups, ks, ncand, 1, kmax, et,
+                    bfs_eval))
+    if posteriors is not None:
+        launches += _launches("fragility", "pgrad", groups, ks,
+                              ncand if frag is not None else 1, 1, kmax,
+                              num_t, bfs_frag)
     profile = {"phase_us": phase_us, "starts": R, "survivors": survivors,
                "pool": ncand, "presolve_num_t": pnt, "eval_num_t": et,
-               "presolve_steps_run": int(n_pre),
-               "refine_steps_run": int(n_ref)}
+               "presolve_steps_run": n_pre, "refine_steps_run": n_ref,
+               "launches": launches}
     return DAGDecision(
-        weights=weights,
-        makespan_mu=float(mk_mu[best]), makespan_var=float(mk_var[best]),
-        stage_mu=np.asarray(smu[best], np.float64),
-        stage_var=np.asarray(svar[best], np.float64),
+        weights=weights, makespan_mu=mk_best, makespan_var=var_best,
+        stage_mu=smu_best, stage_var=svar_best,
         method=method, family_groups=len(groups),
         fragility=frag_best, profile=profile)
 
@@ -813,7 +874,10 @@ def evaluate_dag(dag: StageDAG, weights: Dict[str, np.ndarray],
         makespan_mu=float(mk_mu[0]), makespan_var=float(mk_var[0]),
         stage_mu=np.asarray(smu[0], np.float64),
         stage_var=np.asarray(svar[0], np.float64),
-        method="evaluate", family_groups=len(groups))
+        method="evaluate", family_groups=len(groups),
+        profile={"launches": _launches("final_score", "fwd", groups,
+                                       [s.k for s in dag.stages], 1, 1,
+                                       kmax, num_t, bfs)})
 
 
 def solve_dag_greedy(dag: StageDAG, lam: float = 0.0, steps: int = 120,

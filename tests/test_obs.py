@@ -7,9 +7,14 @@ Acceptance anchors:
   * ring-buffer bounds + drop accounting, name-registry rejection at emit
     time (RPA090's runtime half), tick correlation;
   * exporters round-trip: JSONL read/write, schema validation, Perfetto
-    ``trace_event`` structure, phase totals, Prometheus text;
+    ``trace_event`` structure, phase totals;
+  * a recorded span is also a profiler annotation labelled ``name:attr``;
+    the off path and a non-recording ``timed_span`` never touch the
+    profiler;
   * ``StreamingStat.merge`` equals the concatenated stream on the exact
     moment fields and stays a uniform reservoir on quantiles;
+  * ``solve_dag``'s device waits nest in their phases, and its
+    ``profile["launches"]`` matches a hand count;
   * zero perturbation — the serving engine and the chaos kill/restore
     harness produce bitwise-identical results traced vs untraced, and a
     restored replica's trace carries the restore event with the manifest
@@ -17,6 +22,7 @@ Acceptance anchors:
 """
 import json
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -39,6 +45,14 @@ def tracing():
     obs.set_enabled(prev)
     obs.set_tick(None)
     obs.clear()
+
+
+def _two_width_dag(seed=7):
+    """Three stages in series, 3, 5 and 3 channels wide."""
+    rng = np.random.default_rng(seed)
+    stages = [Stage(n, rng.uniform(10, 30, k), rng.uniform(1, 4, k))
+              for n, k in (("a", 3), ("b", 5), ("c", 3))]
+    return StageDAG(stages, linear_edges(["a", "b", "c"]))
 
 
 def _dag(k=3, seed=7):
@@ -103,6 +117,9 @@ class TestTracer:
         recs = t.records()
         assert len(recs) == 8
         assert [r["attrs"]["i"] for r in recs] == list(range(12, 20))
+        assert [r["attrs"]["i"] for r in t.records(since=recs[-3]["seq"])] \
+            == [18, 19]
+        assert t.records(since=recs[-1]["seq"]) == []
         assert t.dropped() == 12
         t.clear()
         assert t.records() == [] and t.dropped() == 0
@@ -196,14 +213,96 @@ class TestExport:
         assert set(totals) == {"presolve", "refine"}
         assert all(v >= 0 for v in totals.values())
 
-    def test_prometheus_snapshot(self, tracing):
-        text = obs_export.prometheus_snapshot(_sample_records(), dropped=2)
-        assert f'{obs_names.METRIC_SPAN_COUNT}{{kind="solver.phase"}} 2' \
-            in text
-        assert 'quantile="0.50"' in text
-        assert f'{obs_names.METRIC_EVENT_COUNT}' \
-               f'{{type="audit.ckpt_save"}} 1' in text
-        assert text.rstrip().endswith(f"{obs_names.METRIC_DROPPED} 2")
+
+# ---------------------------------------------------------------------------
+# profiler annotations: recorded spans share the device trace's clock
+# ---------------------------------------------------------------------------
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs its use."""
+
+    log = []
+
+    def __init__(self, text):
+        self.text = text
+        self.log.append(("new", text))
+
+    def __enter__(self):
+        self.log.append(("enter", self.text))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.text))
+        return False
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    import jax.profiler
+
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+class TestProfilerAnnotations:
+    def test_recorded_span_opens_and_closes_its_annotation(
+            self, tracing, fake_annotation):
+        with obs.span(obs_names.SPAN_SOLVER_PHASE, phase="presolve"):
+            assert fake_annotation == [("new", "solver.phase:presolve"),
+                                       ("enter", "solver.phase:presolve")]
+        assert fake_annotation[-1] == ("exit", "solver.phase:presolve")
+        with obs.timed_span(obs_names.SPAN_SOLVER_WAIT, phase="refine"):
+            pass
+        assert fake_annotation[3:] == [("new", "solver.wait:refine"),
+                                       ("enter", "solver.wait:refine"),
+                                       ("exit", "solver.wait:refine")]
+        assert len(obs.records()) == 2
+
+    def test_off_path_and_unrecorded_timed_span_skip_the_profiler(
+            self, fake_annotation):
+        assert not obs.enabled()
+        with obs.span(obs_names.SPAN_SOLVER_PHASE, phase="presolve"):
+            pass
+        with obs.timed_span(obs_names.SPAN_SOLVER_PHASE, phase="p") as sp:
+            pass
+        assert sp.dur_us >= 0.0
+        assert fake_annotation == []
+
+    def test_recorded_spans_land_in_the_profiler_trace(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        from repro.workflow import solve_dag
+
+        dag = _two_width_dag()
+        solve_dag(dag, steps=6, restarts=1, num_t=64)    # compile first
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.capture() as cap:
+                solve_dag(dag, steps=6, restarts=1, num_t=64)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        pd = ProfileData.from_file(path)
+        host = sorted(ev.name for plane in pd.planes
+                      if plane.name.startswith("/host:")
+                      for line in plane.lines for ev in line.events
+                      if ev.name.startswith("solver."))
+        spans = sorted(obs.label(r["name"], r["attrs"]) for r in cap
+                       if r["type"] == "span")
+        assert host == spans
+        assert "solver.wait:presolve" in host
+
+    @pytest.mark.parametrize("attrs, want", [
+        ({"phase": "triage"}, "solver.phase:triage"),
+        ({"stage": "commit", "phase": "x"}, "solver.phase:commit"),
+        ({"mode": "grad", "F": 8}, "solver.phase:grad"),
+        ({"F": 8}, "solver.phase"),
+    ])
+    def test_label_names_the_part(self, attrs, want):
+        assert obs.label(obs_names.SPAN_SOLVER_PHASE, attrs) == want
 
 
 # ---------------------------------------------------------------------------
@@ -289,9 +388,96 @@ class TestSolverSpans:
         assert ladder <= set(dec.profile["phase_us"]), dec.profile
         # solve_dag's ops calls run inside jit, so the kernel tier shows
         # up as compile audit events or not at all (warm cache) — never
-        # as in-jit spans (the zero-perturbation jit-boundary rule)
-        assert obs_export.span_kinds(cap) == {obs_names.SPAN_SOLVER_PHASE}
+        # as in-jit spans (the zero-perturbation jit-boundary rule); the
+        # solver's own blocks on the device are its wait spans
+        assert obs_export.span_kinds(cap) == {obs_names.SPAN_SOLVER_PHASE,
+                                              obs_names.SPAN_SOLVER_WAIT}
         obs_export.validate_records(cap)
+
+    @staticmethod
+    def _posteriors(dag):
+        from repro.core.bayes import nig_init, nig_update_batch
+
+        rng = np.random.default_rng(0)
+        out = {}
+        for s in dag.stages:
+            nig = nig_init(s.k, m0=float(np.mean(s.mus)))
+            rates = rng.normal(s.mus, s.sigmas).astype(np.float32)
+            out[s.name] = nig_update_batch(nig, jnp.asarray(rates),
+                                           jnp.ones(s.k, jnp.float32))
+        return out
+
+    @pytest.mark.parametrize("risk", [False, True], ids=["plain", "risk"])
+    def test_waits_lie_inside_their_phase(self, risk):
+        from repro.workflow import solve_dag
+
+        dag = _two_width_dag()
+        kw = (dict(risk_lam=0.5, posteriors=self._posteriors(dag))
+              if risk else {})
+        with obs.capture() as cap:
+            solve_dag(dag, steps=6, restarts=1, num_t=64, **kw)
+        spans = [r for r in cap if r["type"] == "span"]
+        phases = {r["attrs"]["phase"]: r for r in spans
+                  if r["name"] == obs_names.SPAN_SOLVER_PHASE}
+        waits = [r for r in spans if r["name"] == obs_names.SPAN_SOLVER_WAIT]
+        for w in waits:
+            ph = phases[w["attrs"]["phase"]]
+            assert ph["ts_us"] <= w["ts_us"]
+            assert w["ts_us"] + w["dur_us"] <= ph["ts_us"] + ph["dur_us"]
+        # one wait ends each rung; the winner's read-backs close the last
+        # phase: final_score, or fragility, whose one family group reads
+        # its cotangents, its rows and its launch's gradients
+        want = ["presolve", "triage", "refine", "final_score"] + (
+            ["fragility"] * 4 if risk else ["final_score"])
+        assert [w["attrs"]["phase"] for w in waits] == want
+        # the phases are contiguous laps: their sum is the whole solve
+        order = sorted(phases.values(), key=lambda r: r["ts_us"])
+        for a, b in zip(order, order[1:]):
+            assert a["ts_us"] + a["dur_us"] <= b["ts_us"]
+
+    def test_launches_match_a_hand_count(self):
+        from repro.workflow import solve_dag
+
+        dag = _two_width_dag()       # widths 3, 5, 3: 11 real channels
+        dec = solve_dag(dag, steps=6, restarts=1, num_t=64, block_f=4)
+        p = dec.profile
+        R, surv, ncand = p["starts"], p["survivors"], p["pool"]
+        assert R == 3 and ncand == 3 * surv
+
+        def entry(phase, mode, n, launches, num_t):
+            rows = 3 * n
+            bf = min(4, rows)
+            return {"phase": phase, "mode": mode, "family": "normal",
+                    "launches": launches, "rows": rows,
+                    "rows_padded": -(-rows // bf) * bf, "block_f": bf,
+                    "k": 5, "num_t": num_t, "channels": 11 * n}
+
+        assert p["launches"] == [
+            entry("presolve", "grad", R, p["presolve_steps_run"], 64),
+            entry("triage", "fwd", 2 * R, 1, 64),
+            entry("refine", "grad", surv, p["refine_steps_run"], 64),
+            entry("final_score", "fwd", ncand, 1, 2048)]
+        # 3 starts x 3 stages = 9 rows in blocks of 4: 12 padded rows
+        assert p["launches"][0]["rows_padded"] == 12
+        assert p["presolve_steps_run"] == 6
+
+    def test_decision_bitwise_traced_vs_untraced(self):
+        from repro.workflow import solve_dag
+
+        dag = _two_width_dag()
+        plain = solve_dag(dag, steps=6, restarts=1, num_t=64)
+        with obs.capture() as cap:
+            traced = solve_dag(dag, steps=6, restarts=1, num_t=64)
+        assert cap
+        assert plain.weights.keys() == traced.weights.keys()
+        for name in plain.weights:
+            np.testing.assert_array_equal(plain.weights[name],
+                                          traced.weights[name])
+        assert plain.makespan_mu == traced.makespan_mu
+        assert plain.makespan_var == traced.makespan_var
+        np.testing.assert_array_equal(plain.stage_mu, traced.stage_mu)
+        np.testing.assert_array_equal(plain.stage_var, traced.stage_var)
+        assert plain.profile["launches"] == traced.profile["launches"]
 
     def test_kernel_launch_span_attrs(self):
         from repro.kernels import ops
