@@ -517,12 +517,15 @@ class _PhaseClock:
     ``REPRO_TRACE=1``.
 
     ``wait()`` spans a block on the device inside the open phase
-    (``solver.wait``, recorded only when tracing is on).
+    (``solver.wait``, recorded only when tracing is on). ``read(arrays)``
+    is such a wait around ONE batched ``jax.device_get`` of a pytree;
+    ``readbacks`` counts them (``profile["readbacks"]``).
     """
 
     def __init__(self, phase_us: Dict[str, float]):
         self.phase_us = phase_us
         self._open = None
+        self.readbacks = 0
 
     def start(self, phase: str) -> None:
         self._open = obs.timed_span(obs_names.SPAN_SOLVER_PHASE,
@@ -531,6 +534,11 @@ class _PhaseClock:
     def wait(self):
         return obs.span(obs_names.SPAN_SOLVER_WAIT,
                         phase=self._open.attrs["phase"])
+
+    def read(self, arrays):
+        self.readbacks += 1
+        with self.wait():
+            return jax.device_get(arrays)
 
     def lap(self, next_phase: Optional[str] = None) -> None:
         sp = self._open
@@ -616,9 +624,11 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
 
     ``decision.profile`` carries per-phase wall times (``phase_us``) and
     solver counters (starts, survivors, pool size, steps run per phase) so
-    fidelity-ladder wins stay attributable, and ``launches``: the kernel
+    fidelity-ladder wins stay attributable, ``launches``: the kernel
     work of each rung and family group (:func:`_launches`), counted on the
-    host from shapes.
+    host from shapes, and ``readbacks``: the batched device-to-host
+    transfers the ladder made (triage's and the final score's; the winner
+    is indexed on the host from the final score's copy of the pool).
     """
     phase_us: Dict[str, float] = {}
     clock = _PhaseClock(phase_us)
@@ -666,9 +676,9 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     idxs = tuple(g.idx for g in groups)
     stats = tuple((jnp.asarray(g.mus), jnp.asarray(g.sigmas),
                    jnp.asarray(g.extra)) for g in groups)
-    W0 = jnp.asarray(_starts(dag, mask, kmax, restarts, warm_start, key,
-                             upd=upd_np))
-    R = int(W0.shape[0])
+    W0h = _starts(dag, mask, kmax, restarts, warm_start, key, upd=upd_np)
+    W0 = jnp.asarray(W0h)
+    R = int(W0h.shape[0])
     upd = jnp.asarray(upd_np if upd_np is not None
                       else np.ones(S, np.float32))
     pre = presolve_steps if presolve_steps is not None else steps
@@ -703,7 +713,7 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     if _san.enabled():
         # sanitizer tier: eager boundary validation of the stage statistics
         # once, then both jitted phases under checkify (analysis.sanitize)
-        _san.assert_weight_rows(np.asarray(W0))
+        _san.assert_weight_rows(W0h)
         for g in groups:
             _san.assert_finite("stage mus", g.mus)
             _san.assert_finite("stage sigmas", g.sigmas)
@@ -729,11 +739,9 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                     for g in groups)
     c_mu, c_var, _, _ = _score_dag(dag.structure, dist_ids, idxs, stats,
                                    pool0, pnt, impl, bfs_tri)
-    with clock.wait():
-        jax.block_until_ready((c_mu, c_var))
-        csc = np.asarray(c_mu, np.float64) + lam_var * np.asarray(
-            c_var, np.float64)
-        W0h, W1h = np.asarray(W0), np.asarray(W1)
+    c_mu, c_var, W1h = clock.read((c_mu, c_var, W1))
+    csc = np.asarray(c_mu, np.float64) + lam_var * np.asarray(
+        c_var, np.float64)
     per_start = np.minimum(csc[:R], csc[R:])
     Wch = np.where((csc[R:] <= csc[:R])[:, None, None], W1h, W0h)
     if prune_margin is None:
@@ -780,10 +788,12 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                      for g in groups)
     mk_mu, mk_var, smu, svar = _score_dag(dag.structure, dist_ids, idxs,
                                           stats, cands, et, impl, bfs_eval)
-    with clock.wait():
-        jax.block_until_ready((mk_mu, mk_var))
-        score = np.asarray(mk_mu, np.float64) + lam_var * np.asarray(
-            mk_var, np.float64)
+    # the whole pool comes back in the final score's one transfer; the
+    # winner is then indexed on the host, from the same float32 values
+    mk_mu_h, mk_var_h, smu_h, svar_h, cands_h, n_pre, n_ref = clock.read(
+        (mk_mu, mk_var, smu, svar, cands, n_pre, n_ref))
+    score = np.asarray(mk_mu_h, np.float64) + lam_var * np.asarray(
+        mk_var_h, np.float64)
     if posteriors is not None:
         clock.lap("fragility")
 
@@ -813,14 +823,11 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                             cands[best:best + 1], smu[best:best + 1],
                             svar[best:best + 1], num_t, impl, bfs_frag)
         frag_best = float(fb[0])
-    # the winner's read-backs close the last phase: final_score, or
-    # fragility where posteriors were given
-    with clock.wait():
-        Wbest = np.asarray(cands[best], np.float64)
-        mk_best, var_best = float(mk_mu[best]), float(mk_var[best])
-        smu_best = np.asarray(smu[best], np.float64)
-        svar_best = np.asarray(svar[best], np.float64)
-        n_pre, n_ref = int(n_pre), int(n_ref)
+    Wbest = np.asarray(cands_h[best], np.float64)
+    mk_best, var_best = float(mk_mu_h[best]), float(mk_var_h[best])
+    smu_best = np.asarray(smu_h[best], np.float64)
+    svar_best = np.asarray(svar_h[best], np.float64)
+    n_pre, n_ref = int(n_pre), int(n_ref)
     clock.lap()
 
     weights = {s.name: Wbest[i, :s.k] for i, s in enumerate(dag.stages)}
@@ -840,7 +847,7 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     profile = {"phase_us": phase_us, "starts": R, "survivors": survivors,
                "pool": ncand, "presolve_num_t": pnt, "eval_num_t": et,
                "presolve_steps_run": n_pre, "refine_steps_run": n_ref,
-               "launches": launches}
+               "launches": launches, "readbacks": clock.readbacks}
     return DAGDecision(
         weights=weights, makespan_mu=mk_best, makespan_var=var_best,
         stage_mu=smu_best, stage_var=svar_best,

@@ -13,8 +13,9 @@ Acceptance anchors:
     profiler;
   * ``StreamingStat.merge`` equals the concatenated stream on the exact
     moment fields and stays a uniform reservoir on quantiles;
-  * ``solve_dag``'s device waits nest in their phases, and its
-    ``profile["launches"]`` matches a hand count;
+  * ``solve_dag``'s device waits nest in their phases, its
+    ``profile["launches"]`` matches a hand count, and it reads the device
+    back only in its two counted ``jax.device_get`` calls;
   * zero perturbation — the serving engine and the chaos kill/restore
     harness produce bitwise-identical results traced vs untraced, and a
     restored replica's trace carries the restore event with the manifest
@@ -424,11 +425,11 @@ class TestSolverSpans:
             ph = phases[w["attrs"]["phase"]]
             assert ph["ts_us"] <= w["ts_us"]
             assert w["ts_us"] + w["dur_us"] <= ph["ts_us"] + ph["dur_us"]
-        # one wait ends each rung; the winner's read-backs close the last
-        # phase: final_score, or fragility, whose one family group reads
-        # its cotangents, its rows and its launch's gradients
+        # one wait ends each rung, the final score's reading the whole
+        # pool back; with posteriors the fragility's one family group then
+        # reads its cotangents, its rows and its launch's gradients
         want = ["presolve", "triage", "refine", "final_score"] + (
-            ["fragility"] * 4 if risk else ["final_score"])
+            ["fragility"] * 3 if risk else [])
         assert [w["attrs"]["phase"] for w in waits] == want
         # the phases are contiguous laps: their sum is the whole solve
         order = sorted(phases.values(), key=lambda r: r["ts_us"])
@@ -460,6 +461,67 @@ class TestSolverSpans:
         # 3 starts x 3 stages = 9 rows in blocks of 4: 12 padded rows
         assert p["launches"][0]["rows_padded"] == 12
         assert p["presolve_steps_run"] == 6
+
+    @pytest.mark.parametrize("risk", [False, True], ids=["plain", "risk"])
+    def test_one_readback_a_wait(self, risk):
+        from repro.workflow import solve_dag
+
+        dag = _two_width_dag()
+        kw = (dict(risk_lam=0.5, posteriors=self._posteriors(dag))
+              if risk else {})
+        dec = solve_dag(dag, steps=6, restarts=1, num_t=64, **kw)
+        # triage and the final score; the fragility's reads are its own
+        assert dec.profile["readbacks"] == 2
+
+    def test_every_read_is_an_explicit_transfer(self, monkeypatch):
+        """A plain solve reads the device only through ``jax.device_get``.
+
+        The transfer guard refuses implicit reads where the backend copies
+        to the host. The CPU backend lends its buffers to numpy without a
+        copy and never trips it, so two hooks stand in for it there: one on
+        ``ArrayImpl._value`` (``float``, ``int``, ``__array__``) outside an
+        explicit ``device_get``, one on the solver's ``np.asarray`` and
+        ``np.array`` of a device array."""
+        import jax
+        from jax._src import array as jax_array
+        from jax._src.lib import guard_lib
+
+        import repro.workflow.solve as solve_mod
+
+        implicit = []
+        value = jax_array.ArrayImpl._value
+
+        def guarded(self):
+            if not guard_lib.thread_local_state().explicit_device_get:
+                implicit.append(("value", self.shape))
+            return value.fget(self)
+
+        class _Numpy:
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            def _watch(self, fn, a, *args, **kw):
+                if isinstance(a, jax.Array):
+                    implicit.append((fn.__name__, a.shape))
+                return fn(a, *args, **kw)
+
+            def asarray(self, a, *args, **kw):
+                return self._watch(np.asarray, a, *args, **kw)
+
+            def array(self, a, *args, **kw):
+                return self._watch(np.array, a, *args, **kw)
+
+        dag = _two_width_dag()
+        monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(guarded))
+        monkeypatch.setattr(solve_mod, "np", _Numpy())
+        with jax.transfer_guard_device_to_host("disallow"):
+            dec = solve_mod.solve_dag(dag, steps=6, restarts=1, num_t=64)
+            assert implicit == []
+            # the hooks see what the guard would refuse
+            float(jnp.float32(1.0) + 1.0)
+            solve_mod.np.asarray(jnp.zeros(2))
+        assert implicit == [("value", ()), ("asarray", (2,))]
+        assert dec.profile["readbacks"] == 2
 
     def test_decision_bitwise_traced_vs_untraced(self):
         from repro.workflow import solve_dag

@@ -376,6 +376,48 @@ class TestMultiFidelity:
             assert np.array_equal(dec.weights[s.name], dec2.weights[s.name]), \
                 f"frozen stage {s.name} moved"
 
+    @pytest.mark.parametrize("lam_var", [0.0, 0.5])
+    def test_warm_dirty_winner_is_the_pools_best(self, monkeypatch, lam_var):
+        """The winner is indexed from the final score's host copies: its
+        frozen rows are the warm rows bitwise, and it is the final pool's
+        best on the objective, with the pool's own float32 moments."""
+        import repro.workflow.solve as solve_mod
+
+        dag = _diamond(seed=15)
+        dec = solve_dag(dag, steps=30, restarts=0, num_t=256)
+        scored = []
+        score_dag = solve_mod._score_dag
+
+        def record(*a, **k):
+            out = score_dag(*a, **k)
+            scored.append((a[4], out))
+            return out
+
+        monkeypatch.setattr(solve_mod, "_score_dag", record)
+        dec2 = solve_dag(dag, steps=20, restarts=1, num_t=256,
+                         lam_var=lam_var, warm_start=dec.weights,
+                         dirty={"b"})
+        for s in dag.stages:
+            if s.name != "b":
+                assert np.array_equal(dec.weights[s.name],
+                                      dec2.weights[s.name])
+        cands, (mk_mu, mk_var, smu, svar) = (
+            jax.device_get(x) for x in scored[-1])
+        assert cands.shape[0] == dec2.profile["pool"]
+        score = (np.asarray(mk_mu, np.float64)
+                 + lam_var * np.asarray(mk_var, np.float64))
+        best = int(np.argmin(score))
+        assert dec2.makespan_mu + lam_var * dec2.makespan_var == score.min()
+        if lam_var == 0.0:
+            assert dec2.makespan_mu == score.min()
+        assert dec2.makespan_mu == float(mk_mu[best])
+        assert dec2.makespan_var == float(mk_var[best])
+        np.testing.assert_array_equal(dec2.stage_mu, smu[best])
+        np.testing.assert_array_equal(dec2.stage_var, svar[best])
+        for i, s in enumerate(dag.stages):
+            np.testing.assert_array_equal(dec2.weights[s.name],
+                                          cands[best, i, :s.k])
+
     def test_dirty_validation(self):
         dag = _diamond(seed=16)
         with pytest.raises(ValueError, match="warm_start"):
