@@ -50,6 +50,7 @@ from typing import Dict, Optional, Sequence, Tuple
 _log = logging.getLogger(__name__)
 
 __all__ = ["BLOCK_F_CANDIDATES", "ROW_BUCKETS", "vmem_bytes", "pick_block_f",
+           "pack_factor",
            "device_tag",
            "bucket_rows", "lookup", "sweep", "clear_cache",
            "default_cache_path", "cache_state", "load_cache_state"]
@@ -148,15 +149,40 @@ def _mix_tiles(dist_id: str) -> int:
     return EMP_COMPONENTS - 1 if dist_id == "empirical" else 0
 
 
+LANES = 128     # f32 lanes per vreg
+SUBLANES = 8    # f32 sublanes per vreg: the kernels' channel-chunk height
+
+
 def _vmem_tile(rows: int, cols: int) -> int:
     """Bytes of one f32 VMEM buffer: (8, 128) tiles, padded on both axes."""
-    return 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
+    return (4 * (-(-rows // SUBLANES) * SUBLANES)
+            * (-(-cols // LANES) * LANES))
+
+
+def pack_factor(block_f: int, num_k: int) -> int:
+    """Lane slots per row of a ``frontier_grid`` program of ``block_f`` rows
+    and ``num_k`` channels: the kernel's ``pack``, which each launch
+    takes from its own shapes.
+
+    A block fills 128 * ceil(block_f / 128) lanes whatever block_f is; the
+    largest c with block_f * c inside those lanes and ceil(K / c) >= 8 (one
+    sublane chunk per slot) deals each row's channels over c slots, so a
+    program walks ceil(K / c) channels instead of K. 1 where the block
+    leaves no lanes for a second slot or K is too short to split.
+    """
+    lanes = LANES * -(-block_f // LANES)
+    c = lanes // block_f
+    while c > 1 and -(-num_k // c) < SUBLANES:
+        c -= 1
+    return c
 
 
 def vmem_bytes(block_f: int, num_k: int, num_t: int, fused: bool = False,
                dist_id: str = "normal", params: bool = False) -> int:
     """VMEM one kernel program allocates, in bytes (f32), in the
-    channel-major layout of ``frontier_grid``.
+    channel-major layout of ``frontier_grid``, packed by
+    :func:`pack_factor` as the kernel launches it: every (K, bf) tile below is
+    (ceil(K / c), c * bf) for c slots a row.
 
     * Blocks, each double-buffered by the grid pipeline: the (K, bf) tiles
       of W, mus, sigmas and the E extra rows (shared statistics arrive
@@ -172,20 +198,25 @@ def vmem_bytes(block_f: int, num_k: int, num_t: int, fused: bool = False,
       joint CDF, z-scores, the family CDF), more in the fused passes, plus
       the ``empirical`` mixture's per-component tiles.
 
-    Lanes pad to 128, so every block_f below 128 costs what 128 does. The
-    compiler's scoped-VMEM check counts less than this (not every block),
-    so a program this model fits within ``_VMEM_BUDGET_BYTES`` compiles
-    under that limit.
+    * The combine across a packed row's slots: two more (T, bf) tiles.
+
+    Lanes pad to 128, so an unpacked block_f below 128 costs what 128 does.
+    The compiler's scoped-VMEM check counts less than this (not every
+    block), so a program this model fits within ``_VMEM_BUDGET_BYTES``
+    compiles under that limit.
     """
     from repro.core.distributions import extra_rows
-    chan = _vmem_tile(num_k, block_f)
-    blocks = (3 + extra_rows(dist_id)) * chan + 2 * _vmem_tile(1, block_f)
+    c = pack_factor(block_f, num_k)
+    lanes = c * block_f
+    chan = _vmem_tile(-(-num_k // c), lanes)
+    blocks = (3 + extra_rows(dist_id)) * chan + 2 * _vmem_tile(1, lanes)
     scratch = 0
     if fused:
         blocks += (8 if params else 2) * chan
         scratch = (1 + 2 * _grad_acc_pairs(dist_id, params)) * chan
-    work = (12 if fused else 8) + 2 * _mix_tiles(dist_id)
-    return 2 * blocks + scratch + work * _vmem_tile(num_t, block_f)
+    work = ((12 if fused else 8) + 2 * _mix_tiles(dist_id)
+            + (2 if c > 1 else 0))
+    return 2 * blocks + scratch + work * _vmem_tile(num_t, lanes)
 
 
 def _xla_block_bytes(block_f: int, num_k: int, num_t: int, fused: bool,
@@ -199,10 +230,15 @@ def _xla_block_bytes(block_f: int, num_k: int, num_t: int, fused: bool,
 
 
 def _fits(block_f: int, K: int, num_t: int, backend: str, fused: bool,
-          dist_id: str = "normal", params: bool = False) -> bool:
+          dist_id: str = "normal", params: bool = False,
+          F: Optional[int] = None) -> bool:
+    """Whether a candidate block fits the backend's budget. A Pallas
+    program of a candidate above ``F`` rows holds F rows, packed as F."""
     if backend == "xla":
         return (_xla_block_bytes(block_f, K, num_t, fused, dist_id, params)
                 <= _XLA_BLOCK_BUDGET_BYTES)
+    if F is not None:
+        block_f = max(min(block_f, F), 1)
     return (vmem_bytes(block_f, K, num_t, fused, dist_id, params)
             <= _VMEM_BUDGET_BYTES)
 
@@ -213,7 +249,7 @@ def pick_block_f(F: int, K: int, num_t: int, backend: str = "xla",
                  dist_id: str = "normal", params: bool = False) -> int:
     """Largest candidate block_f that fits the backend's budget model."""
     feasible = [bf for bf in candidates
-                if _fits(bf, K, num_t, backend, fused, dist_id, params)]
+                if _fits(bf, K, num_t, backend, fused, dist_id, params, F)]
     pick = max(feasible) if feasible else min(candidates)
     return max(min(pick, F), 1)
 
@@ -331,7 +367,7 @@ def sweep(F: int, K: int, num_t: int, backend: str = "xla",
         family = dist_id
 
     feasible = [bf for bf in candidates
-                if _fits(bf, K, num_t, backend, fused, dist_id, params)]
+                if _fits(bf, K, num_t, backend, fused, dist_id, params, F)]
     if not feasible:
         feasible = [min(candidates)]
     timings = {}

@@ -27,6 +27,21 @@ ever live. ``kernels.autotune.vmem_bytes`` models every buffer of that
 layout (double-buffered blocks, scratch, (T, block_f) work tiles) and the
 launch passes the same budget to the compiler as its scoped-VMEM limit.
 
+Lane packing: a block of ``block_f`` rows fills 128 * ceil(block_f / 128)
+lanes whatever block_f is, and a launch walks its K channels one per step,
+so a launch of few rows leaves lanes empty for K serial steps. Where its
+shapes give a ``pack`` c > 1 (``kernels.autotune.pack_factor`` of block_f
+and K), each row's K channels are dealt over c lane slots: slot j of row
+r is lane r + j * block_f and walks channels j * Kc .. (j + 1) * Kc - 1, Kc = ceil(K / c),
+the channels that pad K to c * Kc being ``w = 0`` point masses (exact, as
+below). The tiles are then (Kc, c * block_f). Each slot's partial log F(t)
+and reach maximum are combined across the row's slots once per launch by
+lane rolls (an add and a max, in float32 on the VPU, gathered onto slot 0
+in slot order and copied back, so every slot of a row holds the same
+bits); the gradient pass walks each slot's own channels against the row's
+full F(t), and its moving-grid sums and argmax-tie count are combined the
+same way. ``pack == 1`` is the unpacked layout above, op for op.
+
 Per-candidate integration grids (t in [0, tmax_f]) keep accuracy uniform
 across candidates whose means differ by orders of magnitude; ``tmax`` uses the
 family's *effective* moments, max_k(mean_k(w) + z std_k(w)).
@@ -185,9 +200,6 @@ def _check_block(F: int, K: int, block_f: int, dist_id: str,
             f"divisibility — call through it, or pass a block_f dividing F.")
 
 
-_SUB = 8  # f32 sublanes per vreg: the channel-chunk height of the K folds
-
-
 def _channel_rows(refs, sl):
     """(w, mus, sigmas, extra) at channel rows ``sl``, each (rows, bf);
     ``extra`` as a tuple of its E rows, which the family math indexes like
@@ -200,26 +212,53 @@ def _channel_rows(refs, sl):
 def _fold_channels(num_k: int, body, carry):
     """Fold ``body(sl, carry)`` over the channels in 8-row sublane chunks at
     aligned dynamic offsets, then over the static remainder rows."""
-    n = num_k // _SUB
+    sub = _at.SUBLANES   # the channel-chunk height of the K folds
+    n = num_k // sub
 
     def chunk(c, acc):
-        return body(pl.ds(pl.multiple_of(c * _SUB, _SUB), _SUB), acc)
+        return body(pl.ds(pl.multiple_of(c * sub, sub), sub), acc)
 
     if n:
         carry = jax.lax.fori_loop(0, n, chunk, carry)
-    if num_k % _SUB:
-        carry = body(pl.ds(n * _SUB, num_k % _SUB), carry)
+    if num_k % sub:
+        carry = body(pl.ds(n * sub, num_k % sub), carry)
     return carry
 
 
-def _prologue(refs, num_t: int, z: float, dist_id: str, reach_ref=None):
+def _combine_slots(x, pack: int, op):
+    """Each row's ``pack`` lane slots of ``x`` (.., pack * bf) combined by
+    ``op``, the result in every slot of the row.
+
+    Slot j of row r is lane r + j * bf. The slots are gathered onto slot 0
+    in slot order (lane r takes lane r + j * bf by a roll), and slot 0's
+    result is rolled back into each other slot, so all slots of a row hold
+    the same bits. ``pack == 1`` returns ``x`` itself.
+    """
+    if pack == 1:
+        return x
+    lanes = x.shape[-1]
+    bf = lanes // pack
+    acc = x
+    for j in range(1, pack):
+        acc = op(acc, pltpu.roll(x, lanes - j * bf, axis=x.ndim - 1))
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    out = acc
+    for j in range(1, pack):
+        out = jnp.where(lane >= j * bf,
+                        pltpu.roll(acc, j * bf, axis=x.ndim - 1), out)
+    return out
+
+
+def _prologue(refs, num_t: int, z: float, dist_id: str, pack: int,
+              reach_ref=None):
     """Grid end, time grid and trapezoid weights shared by both kernels.
 
     Returns ``(amax, tmax, ts, wq)``: the unclamped per-candidate grid end
     (1, bf), its clamp, the (T, bf) time grid and the (T, 1) trapezoid
     weights (end points folded in at 0.5). ``reach_ref`` keeps every
     channel's reach for the adjoint's argmax ties, so the epilogue compares
-    the very values the max was taken over.
+    the very values the max was taken over. With ``pack`` slots a row, the
+    grid end is the maximum over all of the row's slots.
     """
     num_k, block_f = refs[0].shape
 
@@ -236,6 +275,7 @@ def _prologue(refs, num_t: int, z: float, dist_id: str, reach_ref=None):
 
     amax = _fold_channels(num_k, reach_max,
                           jnp.full((1, block_f), -jnp.inf, jnp.float32))
+    amax = _combine_slots(amax, pack, jnp.maximum)
     tmax = jnp.maximum(amax, 1e-12)
     idx = jax.lax.broadcasted_iota(jnp.int32, (num_t, 1), 0)
     ts = tmax * (idx.astype(jnp.float32) / (num_t - 1))       # (T, bf)
@@ -243,22 +283,24 @@ def _prologue(refs, num_t: int, z: float, dist_id: str, reach_ref=None):
     return amax, tmax, ts, wq
 
 
-def _log_joint_cdf(refs, ts, dist_id: str):
-    """log F(t) on the (T, bf) grid: one channel per fori_loop step."""
+def _log_joint_cdf(refs, ts, dist_id: str, pack: int):
+    """log F(t) on the (T, bf) grid: one channel per fori_loop step, each
+    row's slots summed once at the end."""
     def add_channel(kk, logF):
         cdf = dists.family_cdf(dist_id, ts,
                                *_channel_rows(refs, pl.ds(kk, 1)))
         return logF + jnp.log(jnp.clip(cdf, _CDF_FLOOR, 1.0))
 
-    return jax.lax.fori_loop(0, refs[0].shape[0], add_channel,
+    logF = jax.lax.fori_loop(0, refs[0].shape[0], add_channel,
                              jnp.zeros_like(ts))
+    return _combine_slots(logF, pack, jnp.add)
 
 
 def _frontier_kernel(w_ref, mu_ref, sg_ref, ex_ref, mu_out_ref, var_out_ref, *,
-                     num_t: int, z: float, dist_id: str):
+                     num_t: int, z: float, dist_id: str, pack: int):
     refs = (w_ref, mu_ref, sg_ref, ex_ref)   # (K, bf) tiles, extra (E, K, bf)
-    _, tmax, ts, wq = _prologue(refs, num_t, z, dist_id)
-    surv = 1.0 - jnp.exp(_log_joint_cdf(refs, ts, dist_id))  # (T, bf)
+    _, tmax, ts, wq = _prologue(refs, num_t, z, dist_id, pack)
+    surv = 1.0 - jnp.exp(_log_joint_cdf(refs, ts, dist_id, pack))  # (T, bf)
     dt = tmax / (num_t - 1)
     mu = jnp.sum(wq * surv, axis=0, keepdims=True) * dt
     m2 = 2.0 * jnp.sum(wq * ts * surv, axis=0, keepdims=True) * dt
@@ -279,7 +321,18 @@ def _family_extra(dist_id: str, extra, K: int, F=None):
     return extra
 
 
-def _launch_operands(W, mus, sigmas, extra, dist_id: str, block_f: int):
+def _pad_channels(a, kc: int, pack: int, mode: str):
+    """``a`` (..., K) with its last axis padded to pack * kc channels: zero
+    weights (``constant``) or copies of the last channel's statistics
+    (``edge``), so a padding channel is a w = 0 point mass."""
+    pad = pack * kc - a.shape[-1]
+    if not pad:
+        return a
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)], mode=mode)
+
+
+def _launch_operands(W, mus, sigmas, extra, dist_id: str, block_f: int,
+                     pack: int):
     """Channel-major kernel operands and their BlockSpecs.
 
     (F, K) arrays become (G, K, block_f) with G = F // block_f programs, so
@@ -288,18 +341,26 @@ def _launch_operands(W, mus, sigmas, extra, dist_id: str, block_f: int):
     that one block: Mosaic cannot broadcast a (1, 1) value into a (T, bf)
     tile, so a stat must already be a (1, bf) row when the kernel loads it.
     Per-row stats tile along F exactly like W.
+
+    With ``pack`` c > 1 the tiles are (Kc, c * block_f), Kc = ceil(K / c):
+    channel j * Kc + i of row r sits at sublane i of lane r + j * block_f.
     """
     F, K = W.shape
     G = F // block_f
+    kc = -(-K // pack)
+    lane_n = pack * block_f
 
-    def channel_major(a):  # (..., F, K) -> (G, ..., K, block_f)
+    def channel_major(a):  # (..., F, K) -> (G, ..., kc, lane_n)
         lead = a.shape[:-2]
-        a = a.reshape(lead + (G, block_f, K))
+        a = a.reshape(lead + (G, block_f, pack, kc))
         a = jnp.moveaxis(a, len(lead), 0)
-        return jnp.swapaxes(a, -1, -2)
+        a = jnp.swapaxes(a, -1, -3)             # (G, ..., kc, pack, bf)
+        return a.reshape(a.shape[:-3] + (kc, lane_n))
 
-    def lanes(a):  # (..., K) -> (..., K, block_f)
-        return jnp.broadcast_to(a[..., None], a.shape + (block_f,))
+    def lanes(a):  # (..., K) -> (..., kc, lane_n)
+        a = jnp.swapaxes(a.reshape(a.shape[:-1] + (pack, kc)), -1, -2)
+        a = jnp.broadcast_to(a[..., None], a.shape + (block_f,))
+        return a.reshape(a.shape[:-3] + (kc, lane_n))
 
     W = W.astype(jnp.float32)
     mus = jnp.asarray(mus, jnp.float32)
@@ -307,24 +368,39 @@ def _launch_operands(W, mus, sigmas, extra, dist_id: str, block_f: int):
     per_row = mus.ndim == 2
     ex = _family_extra(dist_id, extra, K, F if per_row else None)
     E = ex.shape[0]
-    tile = pl.BlockSpec((None, K, block_f), lambda i: (i, 0, 0))
+    W = _pad_channels(W, kc, pack, "constant")
+    mus, sgs, ex = (_pad_channels(a, kc, pack, "edge")
+                    for a in (mus, sgs, ex))
+    tile = pl.BlockSpec((None, kc, lane_n), lambda i: (i, 0, 0))
     if per_row:
         operands = tuple(channel_major(a) for a in (W, mus, sgs, ex))
         specs = [tile, tile, tile,
-                 pl.BlockSpec((None, E, K, block_f), lambda i: (i, 0, 0, 0))]
+                 pl.BlockSpec((None, E, kc, lane_n), lambda i: (i, 0, 0, 0))]
     else:
         operands = (channel_major(W), lanes(mus), lanes(sgs), lanes(ex))
-        shared = pl.BlockSpec((K, block_f), lambda i: (0, 0))
+        shared = pl.BlockSpec((kc, lane_n), lambda i: (0, 0))
         specs = [tile, shared, shared,
-                 pl.BlockSpec((E, K, block_f), lambda i: (0, 0, 0))]
+                 pl.BlockSpec((E, kc, lane_n), lambda i: (0, 0, 0))]
     return operands, specs
 
 
-def _moment_outputs(G: int, block_f: int):
-    """(specs, shapes) of the two lane-dense (G, 1, block_f) moment rows."""
-    spec = pl.BlockSpec((None, 1, block_f), lambda i: (i, 0, 0))
-    shape = jax.ShapeDtypeStruct((G, 1, block_f), jnp.float32)
+def _moment_outputs(G: int, lane_n: int):
+    """(specs, shapes) of the two lane-dense (G, 1, lane_n) moment rows."""
+    spec = pl.BlockSpec((None, 1, lane_n), lambda i: (i, 0, 0))
+    shape = jax.ShapeDtypeStruct((G, 1, lane_n), jnp.float32)
     return [spec, spec], [shape, shape]
+
+
+def _row_moments(o, F: int, block_f: int, pack: int):
+    """(F,) moments from a (G, 1, pack * block_f) output: slot 0's lanes."""
+    return o.reshape(-1, pack * block_f)[:, :block_f].reshape(F)
+
+
+def _row_channels(o, F: int, K: int, block_f: int, pack: int):
+    """(F, K) per-channel outputs from (G, Kc, pack * block_f) tiles."""
+    G, kc, _ = o.shape
+    o = jnp.transpose(o.reshape(G, kc, pack, block_f), (0, 3, 2, 1))
+    return o.reshape(F, pack * kc)[:, :K]
 
 
 # the scoped-VMEM limit the autotune model budgets against: every block the
@@ -347,17 +423,19 @@ def frontier_grid(W, mus, sigmas, extra=None, *, num_t: int = 1024,
     its own fleet (``extra`` then (E, F, K)); the stat tiles ride the same
     F-blocking as W instead of broadcasting one tile to every program. F
     must be divisible by block_f (ops.py pads with copies of row 0
-    otherwise).
+    otherwise). A block of few rows deals each row's channels over the
+    lanes it would leave empty (module docstring, "Lane packing").
     """
     F, K = W.shape
     block_f = min(block_f, F)
+    pack = _at.pack_factor(block_f, K)
     _check_block(F, K, block_f, dist_id, "fwd")
     operands, in_specs = _launch_operands(W, mus, sigmas, extra, dist_id,
-                                          block_f)
+                                          block_f, pack)
     G = F // block_f
-    out_specs, out_shape = _moment_outputs(G, block_f)
+    out_specs, out_shape = _moment_outputs(G, pack * block_f)
     kernel = functools.partial(_frontier_kernel, num_t=num_t, z=z,
-                               dist_id=dist_id)
+                               dist_id=dist_id, pack=pack)
     mu, var = pl.pallas_call(
         kernel,
         grid=(G,),
@@ -368,13 +446,14 @@ def frontier_grid(W, mus, sigmas, extra=None, *, num_t: int = 1024,
         interpret=interpret,
         name=f"frontier_grid_fwd_{dist_id}",
     )(*operands)
-    return mu.reshape(F), var.reshape(F)
+    return (_row_moments(mu, F, block_f, pack),
+            _row_moments(var, F, block_f, pack))
 
 
 def _frontier_grad_kernel(w_ref, mu_ref, sg_ref, ex_ref,
                           mu_out_ref, var_out_ref, dmu_out_ref, dvar_out_ref,
                           *rest, num_t: int, z: float, dist_id: str,
-                          param_grads: bool):
+                          param_grads: bool, pack: int):
     """Fused forward + analytic adjoint (see module docstring for the math).
 
     Pass 1 is the forward K-loop building the joint log-CDF; pass 2 streams K
@@ -390,14 +469,16 @@ def _frontier_grad_kernel(w_ref, mu_ref, sg_ref, ex_ref,
     (six more (K, bf) outputs): the parameter cotangents contract the SAME
     accumulators against different per-channel constants, so
     full-parameter mode costs extra epilogue arithmetic and output tiles,
-    not a third K-loop.
+    not a third K-loop. With ``pack`` slots a row, each slot walks its own
+    channels against the row's combined F(t); the moving-grid sums and
+    the argmax-tie count are summed over the row's slots before use.
     """
     n_param_outs = 6 if param_grads else 0
     out_refs = (dmu_out_ref, dvar_out_ref) + tuple(rest[:n_param_outs])
     reach_ref, *acc_refs = rest[n_param_outs:]
     refs = (w_ref, mu_ref, sg_ref, ex_ref)
-    amax, tmax, ts, wq = _prologue(refs, num_t, z, dist_id, reach_ref)
-    F_t = jnp.exp(_log_joint_cdf(refs, ts, dist_id))
+    amax, tmax, ts, wq = _prologue(refs, num_t, z, dist_id, pack, reach_ref)
+    F_t = jnp.exp(_log_joint_cdf(refs, ts, dist_id, pack))
     surv = 1.0 - F_t
 
     dt = tmax / (num_t - 1)                                   # (1, bf)
@@ -454,8 +535,9 @@ def _frontier_grad_kernel(w_ref, mu_ref, sg_ref, ex_ref,
         return s_mu, s_var, n_tie
 
     zero_row = jnp.zeros_like(amax)
-    s_mu, s_var, n_tie = _fold_channels(w_ref.shape[0], grid_sums,
-                                        (zero_row,) * 3)
+    s_mu, s_var, n_tie = (
+        _combine_slots(x, pack, jnp.add)
+        for x in _fold_channels(w_ref.shape[0], grid_sums, (zero_row,) * 3))
     b_mu = (mu - dt * s_mu) / tmax
     b_var = 2.0 * (var_raw - dt * s_var) / tmax
     # ties split the tmax cotangent evenly (n_tie >= 1: amax is one of them)
@@ -517,25 +599,29 @@ def frontier_grid_with_grads(W, mus, sigmas, extra=None, *, num_t: int = 1024,
     statistics (``extra`` then (E, F, K)) exactly as in
     :func:`frontier_grid`; the adjoint outputs are per-row either way, so
     only the input tiling changes. F must be divisible by block_f (ops.py
-    pads with copies of row 0 otherwise).
+    pads with copies of row 0 otherwise); lanes packed as in
+    :func:`frontier_grid`.
     """
     F, K = W.shape
     block_f = min(block_f, F)
+    pack = _at.pack_factor(block_f, K)
     _check_block(F, K, block_f, dist_id, "pgrad" if param_grads else "grad")
     operands, in_specs = _launch_operands(W, mus, sigmas, extra, dist_id,
-                                          block_f)
+                                          block_f, pack)
     G = F // block_f
-    out_specs, out_shape = _moment_outputs(G, block_f)
+    kc, lane_n = -(-K // pack), pack * block_f
+    out_specs, out_shape = _moment_outputs(G, lane_n)
     n_fk_outs = 8 if param_grads else 2
-    out_specs += [pl.BlockSpec((None, K, block_f), lambda i: (i, 0, 0))
+    out_specs += [pl.BlockSpec((None, kc, lane_n), lambda i: (i, 0, 0))
                   ] * n_fk_outs
-    out_shape += [jax.ShapeDtypeStruct((G, K, block_f), jnp.float32)
+    out_shape += [jax.ShapeDtypeStruct((G, kc, lane_n), jnp.float32)
                   ] * n_fk_outs
     # the reach rows plus one (P_f, Pv_f) accumulator pair per live feature
     n_acc = 2 * sum(dists.family_features(dist_id, params=param_grads))
-    scratch = [pltpu.VMEM((K, block_f), jnp.float32)] * (1 + n_acc)
+    scratch = [pltpu.VMEM((kc, lane_n), jnp.float32)] * (1 + n_acc)
     kernel = functools.partial(_frontier_grad_kernel, num_t=num_t, z=z,
-                               dist_id=dist_id, param_grads=param_grads)
+                               dist_id=dist_id, param_grads=param_grads,
+                               pack=pack)
     mode = "pgrad" if param_grads else "grad"
     mu, var, *fk = pl.pallas_call(
         kernel,
@@ -548,5 +634,6 @@ def frontier_grid_with_grads(W, mus, sigmas, extra=None, *, num_t: int = 1024,
         interpret=interpret,
         name=f"frontier_grid_{mode}_{dist_id}",
     )(*operands)
-    return (mu.reshape(F), var.reshape(F)) + tuple(
-        jnp.swapaxes(o, 1, 2).reshape(F, K) for o in fk)
+    return (_row_moments(mu, F, block_f, pack),
+            _row_moments(var, F, block_f, pack)) + tuple(
+        _row_channels(o, F, K, block_f, pack) for o in fk)

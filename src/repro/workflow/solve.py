@@ -485,24 +485,32 @@ def _starts(dag: StageDAG, mask: np.ndarray, kmax: int, restarts: int,
 
 
 def _launches(phase: str, mode: str, groups, ks, n: int, launches: int,
-              kmax: int, num_t: int, bfs) -> List[dict]:
+              kmax: int, num_t: int, bfs, impl: str) -> List[dict]:
     """``profile["launches"]`` entries of one rung, one per family group.
 
     The rung ran ``launches`` kernel launches per group, each over the
     group's stages for ``n`` candidates: ``rows`` real rows, padded to a
     multiple of the launch's block (``rows_padded``, as ``ops`` pads them)
     and every row to ``k`` channel slots, of which ``channels`` are real
-    (the stages' own widths), at ``num_t`` grid points.
+    (the stages' own widths), at ``num_t`` grid points. ``pack`` is the
+    lane slots a row (``autotune.pack_factor``; 1 on the XLA path) and
+    ``lanes`` the lanes the launch's programs fill: each program's
+    ``block_f * pack`` rounded up to 128.
     """
     out = []
     for g, bf in zip(groups, bfs):
         rows = n * len(g.idx)
         bf = max(min(bf, rows), 1)
+        padded = -(-rows // bf) * bf
+        pack = 1 if impl == "xla" else autotune.pack_factor(bf, kmax)
         out.append({"phase": phase, "mode": mode, "family": g.dist_id,
                     "launches": int(launches), "rows": rows,
-                    "rows_padded": -(-rows // bf) * bf, "block_f": bf,
+                    "rows_padded": padded, "block_f": bf,
                     "k": kmax, "num_t": num_t,
-                    "channels": n * sum(ks[i] for i in g.idx)})
+                    "channels": n * sum(ks[i] for i in g.idx),
+                    "pack": pack,
+                    "lanes": padded // bf * autotune.LANES
+                    * -(-bf * pack // autotune.LANES)})
     return out
 
 
@@ -834,16 +842,17 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     ks = [s.k for s in dag.stages]
     launches = (
         _launches("presolve", "grad", groups, ks, R, n_pre, kmax, pnt,
-                  bfs_pre)
-        + _launches("triage", "fwd", groups, ks, 2 * R, 1, kmax, pnt, bfs_tri)
+                  bfs_pre, impl)
+        + _launches("triage", "fwd", groups, ks, 2 * R, 1, kmax, pnt, bfs_tri,
+                    impl)
         + _launches("refine", "grad", groups, ks, survivors, n_ref, kmax,
-                    num_t, bfs_ref)
+                    num_t, bfs_ref, impl)
         + _launches("final_score", "fwd", groups, ks, ncand, 1, kmax, et,
-                    bfs_eval))
+                    bfs_eval, impl))
     if posteriors is not None:
         launches += _launches("fragility", "pgrad", groups, ks,
                               ncand if frag is not None else 1, 1, kmax,
-                              num_t, bfs_frag)
+                              num_t, bfs_frag, impl)
     profile = {"phase_us": phase_us, "starts": R, "survivors": survivors,
                "pool": ncand, "presolve_num_t": pnt, "eval_num_t": et,
                "presolve_steps_run": n_pre, "refine_steps_run": n_ref,
@@ -884,7 +893,7 @@ def evaluate_dag(dag: StageDAG, weights: Dict[str, np.ndarray],
         method="evaluate", family_groups=len(groups),
         profile={"launches": _launches("final_score", "fwd", groups,
                                        [s.k for s in dag.stages], 1, 1,
-                                       kmax, num_t, bfs)})
+                                       kmax, num_t, bfs, impl)})
 
 
 def solve_dag_greedy(dag: StageDAG, lam: float = 0.0, steps: int = 120,

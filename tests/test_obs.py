@@ -451,7 +451,9 @@ class TestSolverSpans:
             return {"phase": phase, "mode": mode, "family": "normal",
                     "launches": launches, "rows": rows,
                     "rows_padded": -(-rows // bf) * bf, "block_f": bf,
-                    "k": 5, "num_t": num_t, "channels": 11 * n}
+                    "k": 5, "num_t": num_t, "channels": 11 * n,
+                    # the XLA path packs nothing: one 128-lane group a block
+                    "pack": 1, "lanes": -(-rows // bf) * 128}
 
         assert p["launches"] == [
             entry("presolve", "grad", R, p["presolve_steps_run"], 64),

@@ -81,3 +81,100 @@ def test_engine_stacked_grad_launch_compiles(one_chip):
     text = _compile_text(fn, [(F, K), (F, K), (F, K),
                               (extra_rows(dist_id), F, K)], one_chip)
     assert "tpu_custom_call" in text
+
+
+# every launch shape of a Montage 8-degree solve (bench/configs/montage.json:
+# 11 stages, the widest of 6,172 channels, 3 starts, one survivor and three)
+MONTAGE_K = 6172
+MONTAGE_RUNGS = [("grad", 33, 128), ("fwd", 66, 128), ("grad", 11, 256),
+                 ("grad", 33, 256), ("fwd", 33, 2048), ("fwd", 99, 2048),
+                 ("pgrad", 11, 256), ("pgrad", 99, 256)]
+
+
+@pytest.mark.parametrize("mode,F,T", MONTAGE_RUNGS)
+def test_montage_rung_compiles_packed(one_chip, mode, F, T):
+    """The packed launch of each rung compiles within the scoped-VMEM
+    limit, and the profiler's name for it reads as F * pack rows of
+    ceil(K / pack) channels: the roofline's count of the packed launch is
+    the unpacked launch's within 1%."""
+    from bench import trace_reduce
+    from bench.kernels import frontier_grid as kfg
+
+    K = MONTAGE_K
+    fused, params = mode != "fwd", mode == "pgrad"
+    bf = autotune.pick_block_f(F, K, T, "pallas", fused=fused, params=params)
+    pack = autotune.pack_factor(bf, K)
+    Fp = -(-F // bf) * bf
+    if mode == "fwd":
+        def fn(W, mus, sgs, ex):
+            return frontier_grid(W, mus, sgs, ex, num_t=T, block_f=bf)
+    else:
+        def fn(W, mus, sgs, ex):
+            return frontier_grid_with_grads(W, mus, sgs, ex, num_t=T,
+                                            block_f=bf, param_grads=params)
+    text = _compile_text(fn, [(Fp, K)] * 3 + [(1, Fp, K)], one_chip)
+    line = next(ln.strip() for ln in text.splitlines()
+                if "custom-call(" in ln and "frontier_grid_" in ln)
+    rows, k = trace_reduce.kernel_shape(line)
+    assert (rows, k) == (Fp * pack, -(-K // pack))
+    assert kfg.ops(rows, k, T, mode) == pytest.approx(
+        kfg.ops(Fp, K, T, mode), rel=0.01)
+    assert kfg.bytes_moved(rows, k, mode, "normal") == pytest.approx(
+        kfg.bytes_moved(Fp, K, mode, "normal"), rel=0.01)
+
+
+# sha256 (first 16 hex digits) of the Mosaic module, printed without debug
+# locations, that the kernel as it was before lane packing lowered to for a
+# v5e at these shapes. Each gives pack 1: a block that fills its lanes, or
+# K too short to split.
+_UNPACKED_MOSAIC = {("grad", 135, 329, 128, 135): "02bc203c71fa45cc",
+                    ("fwd", 4096, 1024, 256, 512): "24a015ab07bf20db",
+                    ("pgrad", 128, 1024, 256, 128): "858761eec645a1a8",
+                    ("grad", 64, 12, 64, 8): "f830e7fc59129c14"}
+
+
+def _mosaic_text(lowered) -> str:
+    """The Mosaic module of the one ``tpu_custom_call`` in ``lowered``."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib.mlir import ir
+
+    configs = []
+
+    def visit(op):
+        if op.name == "stablehlo.custom_call":
+            configs.append(ir.StringAttr(op.attributes["backend_config"]).value)
+        for region in op.regions:
+            for block in region.blocks:
+                for child in block.operations:
+                    visit(child.operation)
+
+    visit(lowered.compiler_ir().operation)
+    assert len(configs) == 1
+    body = base64.b64decode(json.loads(configs[0])["custom_call_config"]["body"])
+    ctx = jmlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return ir.Module.parse(body).operation.get_asm(enable_debug_info=False)
+
+
+@pytest.mark.parametrize("mode,F,K,T,bf", sorted(_UNPACKED_MOSAIC))
+def test_pack_one_lowers_to_the_unpacked_program(one_chip, mode, F, K, T, bf):
+    import hashlib
+
+    assert autotune.pack_factor(bf, K) == 1
+    if mode == "fwd":
+        def fn(W, mus, sgs, ex):
+            return frontier_grid(W, mus, sgs, ex, num_t=T, block_f=bf)
+    else:
+        def fn(W, mus, sgs, ex):
+            return frontier_grid_with_grads(W, mus, sgs, ex, num_t=T,
+                                            block_f=bf,
+                                            param_grads=mode == "pgrad")
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in [(F, K)] * 3 + [(1, F, K)]]
+    text = _mosaic_text(jax.jit(fn).lower(*args))
+    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert digest == _UNPACKED_MOSAIC[(mode, F, K, T, bf)]
