@@ -20,7 +20,11 @@ This solver does that with one batched kernel path:
    ``ops.frontier_moments_with_grads``, so ONE fused launch per family —
    not per stage — returns every stage's moments and analytic adjoints.
    An all-one-family DAG (the benchmark) is literally a single launch per
-   PGD step.
+   PGD step. A single-channel stage's split is w = [1] whatever the
+   iterate, so it is no PGD row: its moments come from one launch per
+   phase, at its own width of one channel, and enter the composition as
+   constants. The PGD rows, their projection and their launches hold only
+   the stages whose split can move, padded to the widest of them.
 2. **Compose**: the per-stage ``(mu_s, var_s)`` flow through
    ``dag.compose_moments`` (series sums + Clark joins) to the makespan;
    autodiff runs only over these O(S) Clark folds — the expensive
@@ -186,6 +190,14 @@ def _stage_groups(dag: StageDAG) -> Tuple[List[_Group], np.ndarray, int]:
     return stack_rows([(s.mus, s.sigmas, s.family) for s in dag.stages])
 
 
+def _launch_args(groups):
+    """``(dist_ids, idxs, stats)`` of stacked groups: the static launch
+    specialization and the traced per-row statistics."""
+    return (tuple(g.dist_id for g in groups), tuple(g.idx for g in groups),
+            tuple((jnp.asarray(g.mus), jnp.asarray(g.sigmas),
+                   jnp.asarray(g.extra)) for g in groups))
+
+
 def _project_simplex_masked(v, mask):
     """Held projection onto the simplex of the ACTIVE (mask=1) channels.
 
@@ -233,17 +245,26 @@ def _stage_moments_grads(W, dist_ids, idxs, stats, num_t, impl, bfs):
 
 @partial(jax.jit, static_argnames=("structure", "dist_ids", "idxs", "steps",
                                    "patience", "num_t", "impl", "bfs",
-                                   "composed", "sanitize"))
+                                   "composed", "sanitize", "fixed"))
 def _pgd_phase(structure, dist_ids, idxs, stats, masks, W0, upd, lam_var,
                plateau_tol, steps: int, patience: int, num_t: int,
                impl: str, bfs, composed: bool, lr: float = _PRESOLVE_LR,
-               warmup: int = 0, sanitize: bool = False):
+               warmup: int = 0, sanitize: bool = False, fixed=None,
+               fixed_stats=()):
     """One masked-PGD phase over the stacked stage simplices.
 
     ``composed=False`` descends each stage's LOCAL expected join time (the
     graph-blind presolve objective — the per-row loss decouples into a sum
     of stage means); ``composed=True`` descends the composed makespan
     (fused kernel adjoints chained with the composition's cotangents).
+
+    ``W0`` is the (R, S, Kmax) start stack of every stage. With ``fixed``
+    (static ``(mov, fix, dist_ids, idxs, bfs)``) the stages ``fix`` are
+    single-channel: one launch before the loop, over their family groups
+    one channel wide (``fixed_stats``), gives their moments, which every
+    step composes as constants, and the loop carries only the ``mov``
+    rows, whose groups ``dist_ids``, ``idxs``, ``stats`` and ``masks``
+    describe. ``fixed=None``: every stage is a row.
 
     ``upd`` is the traced (S,) dirty mask of an incremental re-solve: rows
     of frozen stages (``upd == 0``) contribute their moments to the
@@ -264,7 +285,8 @@ def _pgd_phase(structure, dist_ids, idxs, stats, masks, W0, upd, lam_var,
     stops at a mid-schedule step size — the best-iterate tracking below
     makes that safe.
 
-    Returns ``(W_final, W_best, best_loss, steps_run)``: ``W_best`` is the
+    Returns ``(W_final, W_best, best_loss, steps_run)``, the stacks of
+    every stage (fixed rows as in ``W0``): ``W_best`` is the
     best-objective iterate seen per start at THIS phase's fidelity (the
     schedule can overshoot past it; both snapshots join the final pool so
     refinement can explore without ever losing ground).
@@ -272,8 +294,24 @@ def _pgd_phase(structure, dist_ids, idxs, stats, masks, W0, upd, lam_var,
     Static ``sanitize=True`` plants checkify invariant checks per step;
     legal only under ``analysis.sanitize.run_checked`` (see that module).
     """
+    R, S = W0.shape[:2]
+    W_mov = W0
+    take = lambda g: g       # (R, S) cotangents -> those of the loop's rows
+    if fixed is not None:
+        mov, fix, f_ids, f_idxs, f_bfs = fixed
+        W_mov, upd = W0[:, mov, :], upd[jnp.asarray(mov)]
+        take = lambda g: g[:, mov]
+        # the fixed rows' moments, constant through the phase (a grad
+        # launch, so the kernel trace shows it as this phase's work)
+        f_mu, f_var, _, _ = _stage_moments_grads(
+            W0[:, fix, :1], f_ids, f_idxs, fixed_stats, num_t, impl, f_bfs)
+
+        def full(m, f):      # (R, S_mov) moments and the constants -> (R, S)
+            return jnp.zeros((R, S), m.dtype).at[:, mov].set(m) \
+                .at[:, fix].set(f)
+
     proj = jax.vmap(jax.vmap(_project_simplex_masked))
-    masks_b = jnp.broadcast_to(masks, W0.shape)
+    masks_b = jnp.broadcast_to(masks, W_mov.shape)
     upd_b = (upd > 0)[None, :, None]
 
     def loss_one(smu_r, svar_r):
@@ -290,9 +328,11 @@ def _pgd_phase(structure, dist_ids, idxs, stats, masks, W0, upd, lam_var,
         i, W, Wb, row_best, pool_best, stall = c
         smu, svar, dmu, dvar = _stage_moments_grads(
             W, dist_ids, idxs, stats, num_t, impl, bfs)
+        if fixed is not None:
+            smu, svar = full(smu, f_mu), full(svar, f_var)
         if composed:
             losses, (g_mu, g_var) = val_grad(smu, svar)    # (R,), (R, S)
-            G = g_mu[..., None] * dmu + g_var[..., None] * dvar
+            G = take(g_mu)[..., None] * dmu + take(g_var)[..., None] * dvar
         else:
             losses = jnp.sum(smu, axis=1)
             G = dmu                                        # stage-local mean
@@ -313,11 +353,12 @@ def _pgd_phase(structure, dist_ids, idxs, stats, masks, W0, upd, lam_var,
             _san.check_weight_rows(W, "DAG PGD iterate")
         return (i + 1, W, Wb, row_best, pool_best, stall)
 
-    R = W0.shape[0]
     # 1e30, not inf: inf-inf poisons the first plateau comparison
-    init = (jnp.int32(0), W0, W0, jnp.full((R,), 1e30, jnp.float32),
+    init = (jnp.int32(0), W_mov, W_mov, jnp.full((R,), 1e30, jnp.float32),
             jnp.float32(1e30), jnp.int32(0))
     i, W, Wb, row_best, _, _ = jax.lax.while_loop(cond, body, init)
+    if fixed is not None:
+        W, Wb = W0.at[:, mov, :].set(W), W0.at[:, mov, :].set(Wb)
     return W, Wb, row_best, i
 
 
@@ -492,7 +533,9 @@ def _launches(phase: str, mode: str, groups, ks, n: int, launches: int,
     group's stages for ``n`` candidates: ``rows`` real rows, padded to a
     multiple of the launch's block (``rows_padded``, as ``ops`` pads them)
     and every row to ``k`` channel slots, of which ``channels`` are real
-    (the stages' own widths), at ``num_t`` grid points. ``pack`` is the
+    (the stages' own widths, ``ks`` indexed by ``group.idx``), at ``num_t``
+    grid points. A PGD phase books its single-channel stages' one launch
+    (``k`` 1, ``launches`` 1) before its loop's launches. ``pack`` is the
     lane slots a row (``autotune.pack_factor``; 1 on the XLA path) and
     ``lanes`` the lanes the launch's programs fill: each program's
     ``block_f * pack`` rounded up to 128.
@@ -601,6 +644,10 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     Every moment/gradient evaluation runs through ONE stacked
     ``ops.frontier_moments*`` launch per completion-time family present in
     the DAG — stages are rows, never a Python loop over kernel launches.
+    A single-channel stage (split [1] whatever the iterate) is no row of
+    the PGD phases: each phase computes its moments once, in a launch one
+    channel wide, and composes them as constants; triage, the final score
+    and the fragility score every stage, as the returned split holds them.
     Each (fidelity, mode) pair resolves its own autotuned block shape:
     ``num_t`` is part of the autotune key schema, so coarse-rung entries
     never cross-contaminate fine-rung silicon sweeps.
@@ -636,7 +683,8 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     work of each rung and family group (:func:`_launches`), counted on the
     host from shapes, and ``readbacks``: the batched device-to-host
     transfers the ladder made (triage's and the final score's; the winner
-    is indexed on the host from the final score's copy of the pool).
+    is indexed on the host from the final score's copy of the pool), and
+    ``fixed_stages``: the single-channel stages composed as constants.
     """
     phase_us: Dict[str, float] = {}
     clock = _PhaseClock(phase_us)
@@ -680,10 +728,21 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
                            for s in dag.stages], np.float32)
 
     groups, mask, kmax = _stage_groups(dag)
-    dist_ids = tuple(g.dist_id for g in groups)
-    idxs = tuple(g.idx for g in groups)
-    stats = tuple((jnp.asarray(g.mus), jnp.asarray(g.sigmas),
-                   jnp.asarray(g.extra)) for g in groups)
+    dist_ids, idxs, stats = _launch_args(groups)
+    # a single-channel stage's split is [1] at every iterate: the PGD rows
+    # are the stages whose split can move (``mov``), and the others
+    # (``fix``) are composed as constants at their own width of one channel
+    mov = tuple(i for i, s in enumerate(dag.stages) if s.k > 1)
+    fix = tuple(i for i, s in enumerate(dag.stages) if s.k == 1)
+    if fix:
+        stage_rows = [(s.mus, s.sigmas, s.family) for s in dag.stages]
+        groups_m, mask_m, _ = stack_rows([stage_rows[i] for i in mov], kmax)
+        groups_f = stack_rows([stage_rows[i] for i in fix], 1)[0]
+        ids_m, idxs_m, stats_m = _launch_args(groups_m)
+    else:
+        groups_m, mask_m, groups_f = groups, mask, []
+        ids_m, idxs_m, stats_m = dist_ids, idxs, stats
+    ids_f, idxs_f, stats_f = _launch_args(groups_f)
     W0h = _starts(dag, mask, kmax, restarts, warm_start, key, upd=upd_np)
     W0 = jnp.asarray(W0h)
     R = int(W0h.shape[0])
@@ -697,26 +756,33 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     # fused pgrad working set is ~4x the grad one, the eval pass runs a
     # larger grid, and T is part of the autotune key so the coarse rung's
     # swept entries never shadow the fine rung's
-    def _bf(g, rows, nt, fused, params):
+    def _bf(g, rows, nt, fused, params, k=kmax):
         if block_f is not None:
             return max(min(block_f, rows), 1)
-        return autotune.lookup(rows, kmax, nt, backend=impl, fused=fused,
+        return autotune.lookup(rows, k, nt, backend=impl, fused=fused,
                                dist_id=g.dist_id, params=params,
                                stacked=True)
 
-    def _run_phase(W_in, bfs_p, composed, n_steps, nt, pat, lr, warmup):
-        if _san.enabled():
-            return _san.run_checked(
-                partial(_pgd_phase, steps=n_steps, patience=pat, num_t=nt,
-                        impl=impl, bfs=bfs_p, composed=composed, lr=lr,
-                        warmup=warmup, sanitize=True),
-                dag.structure, dist_ids, idxs, stats, jnp.asarray(mask),
+    def _run_phase(W_in, n, composed, n_steps, nt, lr, warmup):
+        """A PGD phase over ``n`` candidates: ``(outputs, bfs, fixed bfs)``.
+        With no movable stage it returns its start, in no step."""
+        bfs_m = tuple(_bf(g, n * len(g.idx), nt, True, False)
+                      for g in groups_m)
+        bfs_f = tuple(_bf(g, n * len(g.idx), nt, True, False, 1)
+                      for g in groups_f)
+        if not mov:
+            return (W_in, W_in, None, 0), bfs_m, bfs_f
+        kw = dict(steps=n_steps, patience=patience, num_t=nt, impl=impl,
+                  bfs=bfs_m, composed=composed, lr=lr, warmup=warmup,
+                  fixed=(mov, fix, ids_f, idxs_f, bfs_f) if fix else None)
+        args = (dag.structure, ids_m, idxs_m, stats_m, jnp.asarray(mask_m),
                 W_in, upd, jnp.float32(lam_var), jnp.float32(plateau_tol))
-        return _pgd_phase(dag.structure, dist_ids, idxs, stats,
-                          jnp.asarray(mask), W_in, upd,
-                          jnp.float32(lam_var), jnp.float32(plateau_tol),
-                          n_steps, pat, nt, impl, bfs_p, composed,
-                          lr=lr, warmup=warmup)
+        if _san.enabled():
+            out = _san.run_checked(partial(_pgd_phase, sanitize=True, **kw),
+                                   *args, fixed_stats=stats_f)
+        else:
+            out = _pgd_phase(*args, fixed_stats=stats_f, **kw)
+        return out, bfs_m, bfs_f
 
     if _san.enabled():
         # sanitizer tier: eager boundary validation of the stage statistics
@@ -732,9 +798,8 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     # --- phase 1: stage-local presolve at the coarse rung; stall counting
     # waits out the first half of the cosine schedule (cold starts spend it
     # in large-step transit where the pool best moves in bursts)
-    bfs_pre = tuple(_bf(g, R * len(g.idx), pnt, True, False) for g in groups)
-    W1, _, _, n_pre = _run_phase(W0, bfs_pre, False, pre, pnt, patience,
-                                 _PRESOLVE_LR, pre // 2)
+    (W1, _, _, n_pre), bfs_pre, bfs_pre_f = _run_phase(
+        W0, R, False, pre, pnt, _PRESOLVE_LR, pre // 2)
     with clock.wait():
         jax.block_until_ready(W1)
     clock.lap("triage")
@@ -780,10 +845,8 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     # survivors are presolved (near-frontier) so the step is small, but the
     # fixed-size normalized-gradient steps still orbit the optimum until the
     # cosine decay shrinks them — stalls count from mid-schedule here too
-    bfs_ref = tuple(_bf(g, survivors * len(g.idx), num_t, True, False)
-                    for g in groups)
-    Wf, Wb, _, n_ref = _run_phase(Wr0, bfs_ref, True, steps, num_t, patience,
-                                  _REFINE_LR, steps // 2)
+    (Wf, Wb, _, n_ref), bfs_ref, bfs_ref_f = _run_phase(
+        Wr0, survivors, True, steps, num_t, _REFINE_LR, steps // 2)
     with clock.wait():
         jax.block_until_ready(Wf)
     clock.lap("final_score")
@@ -840,13 +903,21 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
 
     weights = {s.name: Wbest[i, :s.k] for i, s in enumerate(dag.stages)}
     ks = [s.k for s in dag.stages]
+
+    def pgd_launches(phase, n, steps_run, nt, bfs_m, bfs_f):
+        # the fixed rows' one launch of the phase, then the loop's
+        if not mov:
+            return []
+        return (_launches(phase, "grad", groups_f, [1] * len(fix), n, 1, 1,
+                          nt, bfs_f, impl)
+                + _launches(phase, "grad", groups_m, [ks[i] for i in mov], n,
+                            steps_run, kmax, nt, bfs_m, impl))
+
     launches = (
-        _launches("presolve", "grad", groups, ks, R, n_pre, kmax, pnt,
-                  bfs_pre, impl)
+        pgd_launches("presolve", R, n_pre, pnt, bfs_pre, bfs_pre_f)
         + _launches("triage", "fwd", groups, ks, 2 * R, 1, kmax, pnt, bfs_tri,
                     impl)
-        + _launches("refine", "grad", groups, ks, survivors, n_ref, kmax,
-                    num_t, bfs_ref, impl)
+        + pgd_launches("refine", survivors, n_ref, num_t, bfs_ref, bfs_ref_f)
         + _launches("final_score", "fwd", groups, ks, ncand, 1, kmax, et,
                     bfs_eval, impl))
     if posteriors is not None:
@@ -856,7 +927,8 @@ def solve_dag(dag: StageDAG, lam_var: float = 0.0, steps: int = 120,
     profile = {"phase_us": phase_us, "starts": R, "survivors": survivors,
                "pool": ncand, "presolve_num_t": pnt, "eval_num_t": et,
                "presolve_steps_run": n_pre, "refine_steps_run": n_ref,
-               "launches": launches, "readbacks": clock.readbacks}
+               "launches": launches, "readbacks": clock.readbacks,
+               "fixed_stages": len(fix)}
     return DAGDecision(
         weights=weights, makespan_mu=mk_best, makespan_var=var_best,
         stage_mu=smu_best, stage_var=svar_best,
@@ -869,10 +941,7 @@ def evaluate_dag(dag: StageDAG, weights: Dict[str, np.ndarray],
     """Composed moments of an arbitrary per-stage split (shared evaluator:
     joint and greedy decisions are compared on the SAME quadrature)."""
     groups, mask, kmax = _stage_groups(dag)
-    dist_ids = tuple(g.dist_id for g in groups)
-    idxs = tuple(g.idx for g in groups)
-    stats = tuple((jnp.asarray(g.mus), jnp.asarray(g.sigmas),
-                   jnp.asarray(g.extra)) for g in groups)
+    dist_ids, idxs, stats = _launch_args(groups)
     S = len(dag.stages)
     W = np.zeros((1, S, kmax), np.float32)
     for i, s in enumerate(dag.stages):

@@ -56,6 +56,14 @@ def _two_width_dag(seed=7):
     return StageDAG(stages, linear_edges(["a", "b", "c"]))
 
 
+def _fixed_stage_dag(seed=7):
+    """Three stages in series, 3, 1 and 5 channels wide."""
+    rng = np.random.default_rng(seed)
+    stages = [Stage(n, rng.uniform(10, 30, k), rng.uniform(1, 4, k))
+              for n, k in (("a", 3), ("b", 1), ("c", 5))]
+    return StageDAG(stages, linear_edges(["a", "b", "c"]))
+
+
 def _dag(k=3, seed=7):
     rng = np.random.default_rng(seed)
     stages = [Stage("a", rng.uniform(10, 30, k), rng.uniform(1, 4, k)),
@@ -462,6 +470,41 @@ class TestSolverSpans:
             entry("final_score", "fwd", ncand, 1, 2048)]
         # 3 starts x 3 stages = 9 rows in blocks of 4: 12 padded rows
         assert p["launches"][0]["rows_padded"] == 12
+        assert p["presolve_steps_run"] == 6
+
+    def test_launches_match_a_hand_count_with_a_fixed_stage(self):
+        """A single-channel stage is no PGD row: each phase books one
+        launch of its one-channel row a candidate, and the loop's launches
+        hold the other two stages; triage and the final score every
+        stage."""
+        from repro.workflow import solve_dag
+
+        dag = _fixed_stage_dag()     # widths 3, 1, 5: 9 real channels
+        dec = solve_dag(dag, steps=6, restarts=1, num_t=64, block_f=4)
+        p = dec.profile
+        R, surv, ncand = p["starts"], p["survivors"], p["pool"]
+        assert R == 3 and ncand == 3 * surv and p["fixed_stages"] == 1
+
+        def entry(phase, mode, n, launches, num_t, stages, k, channels):
+            rows = stages * n
+            bf = min(4, rows)
+            return {"phase": phase, "mode": mode, "family": "normal",
+                    "launches": launches, "rows": rows,
+                    "rows_padded": -(-rows // bf) * bf, "block_f": bf,
+                    "k": k, "num_t": num_t, "channels": channels * n,
+                    "pack": 1, "lanes": -(-rows // bf) * 128}
+
+        assert p["launches"] == [
+            entry("presolve", "grad", R, 1, 64, 1, 1, 1),
+            entry("presolve", "grad", R, p["presolve_steps_run"], 64, 2, 5,
+                  8),
+            entry("triage", "fwd", 2 * R, 1, 64, 3, 5, 9),
+            entry("refine", "grad", surv, 1, 64, 1, 1, 1),
+            entry("refine", "grad", surv, p["refine_steps_run"], 64, 2, 5,
+                  8),
+            entry("final_score", "fwd", ncand, 1, 2048, 3, 5, 9)]
+        # 3 starts x 2 movable stages = 6 rows in blocks of 4: 8 padded
+        assert p["launches"][1]["rows_padded"] == 8
         assert p["presolve_steps_run"] == 6
 
     @pytest.mark.parametrize("risk", [False, True], ids=["plain", "risk"])

@@ -1,6 +1,10 @@
 """Workflow subsystem: StageDAG validation + composition, the stacked
 per-row-statistics kernel layout (``stack_rows``), the joint solver, and
 the runtime twins (WorkflowBalancer / WorkflowSim)."""
+import importlib.util
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -449,6 +453,96 @@ class TestMultiFidelity:
         fine = _key(8, 64, 2048, "xla", False, stacked=True)
         assert coarse != fine
         assert "T128" in coarse and "T2048" in fine
+
+
+def _parity_module():
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "fixtures", "fixed_stage_parity.py")
+    spec = importlib.util.spec_from_file_location("fixed_stage_parity", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_PARITY = _parity_module()
+
+
+class TestFixedStages:
+    """Single-channel stages are composed as constants, never PGD rows:
+    the solver does the arithmetic it did when they were rows."""
+
+    @pytest.mark.parametrize("impl", _PARITY.IMPLS)
+    @pytest.mark.parametrize("case", [c[0] for c in _PARITY.CASES])
+    def test_decisions_match_the_solver_with_fixed_rows(self, case, impl):
+        """Decisions equal those of the solver that carried single-channel
+        stages as PGD rows (``tests/fixtures/fixed_stage_parity.py`` says
+        how the fixture was made): bitwise on the XLA path, where each
+        row's moments do not depend on the launch's other rows, and for a
+        DAG with no single-channel stage, which runs the same program.
+
+        Under the Pallas interpreter a launch's float32 sums follow the
+        shape of its block, so a stage's moments move with the rows beside
+        it: the fixture's own solver, given ``block_f=2``, moves its
+        ``stage_var`` by up to 2.2e-5 of itself. The moments are held to
+        1e-6 of themselves, ``stage_var`` to 1e-6 of the stage mean's
+        square (the scale a variance lives on), the splits to 1e-5."""
+        with open(_PARITY.PATH) as f:
+            want = json.load(f)[case][impl]
+        got = _PARITY.decision(_PARITY.solve(case, impl))
+        if impl == "xla" or all(len(w) > 1 for w in want["weights"].values()):
+            assert got == want
+            return
+        for key in ("makespan_mu", "makespan_var"):
+            assert got[key] == pytest.approx(want[key], rel=1e-6)
+        np.testing.assert_allclose(got["stage_mu"], want["stage_mu"],
+                                   rtol=1e-6)
+        gap = np.abs(np.subtract(got["stage_var"], want["stage_var"]))
+        assert np.all(gap <= 1e-6 * np.square(want["stage_mu"])), gap
+        for name, w in want["weights"].items():
+            np.testing.assert_allclose(got["weights"][name], w, rtol=0,
+                                       atol=1e-5)
+
+    def test_all_single_channel_dag_makes_no_pgd_launch(self, monkeypatch):
+        """With no split that can move, both PGD phases are skipped and
+        the all-ones split comes back, scored at evaluation fidelity."""
+        import repro.workflow.solve as solve_mod
+
+        def boom(*a, **k):
+            raise AssertionError("PGD launched with no movable stage")
+
+        monkeypatch.setattr(solve_mod, "_pgd_phase", boom)
+        stages = [_mk_stage(n, 1, seed=i) for i, n in enumerate("abc")]
+        dag = StageDAG(stages, [("a", "b"), ("a", "c")])
+        dec = solve_dag(dag, steps=8, restarts=1, num_t=32, eval_num_t=64)
+        assert {n: w.tolist() for n, w in dec.weights.items()} == \
+            {n: [1.0] for n in "abc"}
+        prof = dec.profile
+        assert prof["fixed_stages"] == 3
+        assert [e["phase"] for e in prof["launches"]] == ["triage",
+                                                          "final_score"]
+        assert prof["presolve_steps_run"] == prof["refine_steps_run"] == 0
+        ev = evaluate_dag(dag, dec.weights, num_t=64)
+        assert dec.makespan_mu == pytest.approx(ev.makespan_mu, rel=1e-6)
+        assert dec.makespan_var == pytest.approx(ev.makespan_var, rel=1e-6)
+
+    @pytest.mark.parametrize("dirty", [("c",), ("b", "c"), ("b",)],
+                             ids=["movable", "both", "fixed-only"])
+    def test_dirty_resolve_passes_frozen_rows_bitwise(self, dirty):
+        """A re-solve of some stages of a DAG with single-channel stages:
+        every stage outside the dirty set keeps its warm split bitwise,
+        and every single-channel stage keeps [1]."""
+        dag = _PARITY.dag("chain")
+        dec = solve_dag(dag, steps=12, restarts=1, num_t=32)
+        dec2 = solve_dag(dag, steps=12, restarts=1, num_t=32,
+                         warm_start=dec.weights, dirty=set(dirty))
+        assert dec2.method == "pgd-dag-joint-inc"
+        assert dec2.profile["fixed_stages"] == 2
+        for s in dag.stages:
+            if s.k == 1:
+                assert dec2.weights[s.name].tolist() == [1.0]
+            if s.name not in dirty:
+                assert np.array_equal(dec.weights[s.name],
+                                      dec2.weights[s.name]), s.name
 
 
 class TestIncrementalBalancer:
