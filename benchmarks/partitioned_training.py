@@ -1,6 +1,6 @@
 """Beyond-paper: the partitioner inside a real training loop.
 
-Trains a tiny LM for a few hundred simulated steps on 2 heterogeneous pods
+Runs a few hundred simulated training steps on 2 heterogeneous pods
 (one fast/stable, one slow/noisy) under three scheduling policies and compares
 realized per-step join times AND training throughput (tokens/s against the
 simulated clock). This is paper Fig 3/4 logic transplanted onto the gradient

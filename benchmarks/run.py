@@ -10,7 +10,7 @@ import sys
 import traceback
 
 from . import (cluster_scale, fig1_theory, fig2_frontier, fig34_convex_opt,
-               fig56_file_transfer, partitioned_training, roofline_table)
+               fig56_file_transfer, partitioned_training)
 
 SUITES = {
     "fig1": fig1_theory,
@@ -19,7 +19,6 @@ SUITES = {
     "fig56": fig56_file_transfer,
     "cluster": cluster_scale,
     "parttrain": partitioned_training,
-    "roofline": roofline_table,
 }
 
 

@@ -1,9 +1,8 @@
 """Serving driver: batched requests partitioned across two replica groups by
 the paper's frontier — the file-transfer experiment (Figs 5/6) as a serving
-system. Real tiny-model generation per group (--execute), simulated
-replica-speed physics, online learning of the split.
+system. Simulated replica-speed physics, online learning of the split.
 
-Run:  PYTHONPATH=src python examples/serve_partitioned.py --batches 60 --execute
+Run:  PYTHONPATH=src python examples/serve_partitioned.py --batches 60
 
 ``--engine`` demos the continuous-batching tier instead: many concurrent
 workflow instances (mixed templates, SLO deadlines) admitted from a queue,
@@ -76,9 +75,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=60)
     ap.add_argument("--requests", type=int, default=64)
-    ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--execute", action="store_true",
-                    help="actually run generation on tiny models")
     # PR 4 estimation-loop knobs, exposed end-to-end: the balancer behind
     # the batcher accepts these; the example now lets you drive them
     ap.add_argument("--family", default="normal",
@@ -101,20 +97,11 @@ def main():
         run_engine_demo(ticks=args.ticks)
         return
 
-    import jax
-
-    from repro.configs import get_config
-    from repro.models import build_model
-    from repro.serve import PartitionedBatcher, ReplicaGroup, ServeEngine
+    from repro.serve import PartitionedBatcher, ReplicaGroup
     from repro.sim import Channel, ClusterSim
 
-    cfg = get_config("smollm-360m").tiny().replace(remat=False)
     groups = [ReplicaGroup("overlay-path"), ReplicaGroup("direct-path")]
-    if args.execute:
-        for g in groups:
-            m = build_model(cfg)
-            g.engine = ServeEngine(m, cfg)
-            g.params = m.init(jax.random.PRNGKey(0))
+    requests = np.arange(args.requests)
 
     results = {}
     for policy in ("equal", "frontier"):
@@ -124,14 +111,9 @@ def main():
                                      risk_lam=args.risk_lam,
                                      adaptive_refresh=args.adaptive_refresh,
                                      refresh_every=args.refresh_every)
-        rng = np.random.default_rng(0)
         lat = []
         for i in range(args.batches):
-            prompts = rng.integers(0, cfg.vocab_size,
-                                   (args.requests, 12)).astype(np.int32)
-            t, counts, resp = batcher.run_batch(
-                prompts, max_new=args.max_new,
-                execute=args.execute and policy == "frontier" and i < 2)
+            t, counts = batcher.run_batch(requests)
             lat.append(t)
             if i % 20 == 0:
                 tick = batcher.last_tick

@@ -1,77 +1,12 @@
-"""Generate the §Dry-run and §Roofline markdown tables from dry-run JSONs."""
-import glob
+"""Generate the §Workflow-DAG markdown table from BENCH_dag_scale.json."""
 import json
 import os
-import sys
 
-DRY = sys.argv[1] if len(sys.argv) > 1 else "experiments/dryrun"
-
-recs = []
-for p in sorted(glob.glob(os.path.join(DRY, "*.json"))):
-    with open(p) as f:
-        recs.append(json.load(f))
-
-order_shape = {"train_4k": 0, "prefill_32k": 1, "decode_32k": 2, "long_500k": 3,
-               "train_4k(partitioned)": 4}
-recs.sort(key=lambda r: (r["arch"], order_shape.get(r["shape"], 9), r["mesh"]))
-
-
-def fmt_bytes(b):
-    if b is None:
-        return "-"
-    return f"{b/2**30:.2f}"
-
-
-print("### Dry-run table (per-device memory_analysis, compile status)\n")
-print("| arch | shape | mesh | status | args GiB/dev | temp GiB/dev | "
-      "compile s | collectives (count by type) |")
-print("|---|---|---|---|---|---|---|---|")
-for r in recs:
-    if r["status"] == "skipped":
-        print(f"| {r['arch']} | {r['shape']} | {r['mesh']} | SKIP | - | - | - | "
-              f"{r['reason'][:60]} |")
-        continue
-    if r["status"] != "ok":
-        print(f"| {r['arch']} | {r['shape']} | {r['mesh']} | **FAIL** | - | - | - | "
-              f"{r.get('error','')[:60]} |")
-        continue
-    m = r["memory_analysis"]
-    cc = r["hlo_stats"]["collective_counts"]
-    abbrev = {"all-gather": "ag", "all-reduce": "ar", "reduce-scatter": "rs",
-              "all-to-all": "a2a", "collective-permute": "cp"}
-    cstr = ", ".join(f"{abbrev.get(k, k)}:{v}" for k, v in sorted(cc.items()))
-    print(f"| {r['arch']} | {r['shape']} | {r['mesh']} | ok | "
-          f"{fmt_bytes(m['argument_bytes'])} | {fmt_bytes(m['temp_bytes'])} | "
-          f"{r['compile_s']} | {cstr} |")
-
-print("\n\n### Roofline table (seconds per step per chip; dominant term bold)\n")
-print("| arch | shape | mesh | compute_s | memory_s | collective_s (ici/dcn) | "
-      "dominant | bound ms | roofline frac | useful/HLO flops |")
-print("|---|---|---|---|---|---|---|---|---|---|")
-for r in recs:
-    if r["status"] != "ok":
-        continue
-    t = r["roofline"]
-    print(f"| {r['arch']} | {r['shape']} | {r['mesh']} "
-          f"| {t['compute_s']:.4f} | {t['memory_s']:.4f} "
-          f"| {t['collective_s']:.4f} ({t['ici_s']:.3f}/{t['dcn_s']:.3f}) "
-          f"| {t['dominant'].replace('_s','')} "
-          f"| {t['step_lower_bound_s']*1e3:.1f} "
-          f"| {t['roofline_fraction']:.4f} "
-          f"| {(r.get('useful_flops_ratio') or 0):.3f} |")
-
-n_ok = sum(r["status"] == "ok" for r in recs)
-n_skip = sum(r["status"] == "skipped" for r in recs)
-n_fail = len(recs) - n_ok - n_skip
-print(f"\n\ncells: {n_ok} ok, {n_skip} skipped (per assignment rules), {n_fail} failed")
-
-
-# §Workflow-DAG table: joint vs stage-by-stage greedy from BENCH_dag_scale.json
 dag_path = "BENCH_dag_scale.json"
 if os.path.exists(dag_path):
     with open(dag_path) as f:
         d = json.load(f)
-    print("\n\n### Workflow-DAG partitioning "
+    print("### Workflow-DAG partitioning "
           f"({d['stages']} stages x K={d['channels']}; joint solve vs "
           "stage-by-stage greedy)\n")
     print("| method | E[makespan] | Var[makespan] | realized E[makespan] "

@@ -81,7 +81,11 @@ def _unflatten_like(template, flat: dict):
                 f"expected {tuple(np.shape(leaf))} (template), found "
                 f"{tuple(arr.shape)} (checkpoint); the run being restored "
                 f"was saved with a different fleet/model shape")
-        leaves.append(arr.astype(np.asarray(leaf).dtype))
+        dtype = np.asarray(leaf).dtype
+        if arr.dtype.kind == "V" and arr.dtype.itemsize == dtype.itemsize:
+            # npz keeps extension dtypes (bfloat16) as raw bytes; reinterpret
+            arr = arr.view(dtype)
+        leaves.append(arr.astype(dtype))
     return jax.tree_util.tree_unflatten(paths_leaves[1], leaves)
 
 

@@ -1,13 +1,17 @@
-"""Jit'd dispatch wrappers for the kernel package.
+"""Dispatch for ``frontier_grid``, the candidate-split moment evaluation.
 
-Models call these with an ``impl`` string from the run config:
+:func:`frontier_moments` (forward, differentiable through a custom VJP) and
+:func:`frontier_moments_with_grads` (one fused launch with the analytic
+adjoints) are how every solver, the balancer and the serving engine
+evaluate splits. ``impl`` picks the backend:
 
-    "xla"              — pure-jnp reference path (CPU dry-run / correctness; XLA
-                         fuses these well and it is the portable fallback)
+    "xla"              — pure-jnp reference path (``ref.frontier_grid_ref``
+                         streamed through ``lax.map``; the portable fallback)
     "pallas"           — compiled Pallas TPU kernel (real-hardware path)
     "pallas_interpret" — Pallas kernel body executed in Python (CPU validation)
 
-The wrappers own padding/shape glue so kernels can assume aligned shapes.
+The wrappers own padding/shape glue and resolve the launch shape through
+``kernels.autotune``, so the kernel can assume aligned shapes.
 """
 from __future__ import annotations
 
@@ -22,15 +26,10 @@ from ..obs import events as _obs_events
 from ..obs import names as _obs_names
 from ..obs import trace as _obs
 from . import autotune as _at
-from . import flash_attention as _fa
-from . import flash_decode as _fd
 from . import frontier_grid as _fg
-from . import rmsnorm as _rn
-from . import ssd_scan as _ssd
 from . import ref
 
-__all__ = ["attention", "decode_attention", "ssd", "rmsnorm",
-           "frontier_moments", "frontier_moments_with_grads", "IMPLS"]
+__all__ = ["frontier_moments", "frontier_moments_with_grads", "IMPLS"]
 
 IMPLS = ("xla", "pallas", "pallas_interpret")
 
@@ -38,160 +37,6 @@ IMPLS = ("xla", "pallas", "pallas_interpret")
 def _check(impl: str) -> None:
     if impl not in IMPLS:
         raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
-
-
-def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
-              sm_scale: Optional[float] = None, impl: str = "xla",
-              block_q: int = 128, block_k: int = 128, xla_q_chunk: int = 512):
-    """GQA flash attention. q: (B,Hq,S,D); k,v: (B,Hkv,S,D).
-
-    The "xla" path switches to a scan-over-query-chunks formulation beyond
-    ``xla_q_chunk`` so long-context cells never materialize (S, S) logits;
-    sliding-window configs additionally restrict keys to the band.
-    """
-    _check(impl)
-    if impl == "xla":
-        Sq = q.shape[2]
-        if Sq <= xla_q_chunk or Sq != k.shape[2] or Sq % xla_q_chunk:
-            return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                           sm_scale=sm_scale)
-        return _xla_chunked_attention(q, k, v, causal=causal, window=window,
-                                      sm_scale=sm_scale, q_chunk=xla_q_chunk)
-    S = q.shape[2]
-    bq, bk = min(block_q, S), min(block_k, S)
-    if S % bq or S % bk:  # pad sequence to block multiple; extra keys masked by causal
-        raise ValueError(f"seq {S} must be divisible by blocks ({bq},{bk})")
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               sm_scale=sm_scale, block_q=bq, block_k=bk,
-                               interpret=(impl == "pallas_interpret"))
-
-
-def _xla_chunked_attention(q, k, v, *, causal, window, sm_scale, q_chunk):
-    """Memory-bounded attention in pure XLA: lax.scan over query chunks.
-
-    Peak intermediate is (B, Hq, q_chunk, Skv) instead of (B, Hq, S, S).
-    For sliding-window attention only the (window + q_chunk) key band is
-    gathered per chunk, making 32k-seq SWA prefill O(S * window).
-    """
-    import jax
-
-    B, Hq, S, D = q.shape
-    Hkv, Dv = k.shape[1], v.shape[-1]
-    group = Hq // Hkv
-    scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
-    nq = S // q_chunk
-    kx = jnp.repeat(k, group, axis=1)
-    vx = jnp.repeat(v, group, axis=1)
-
-    band = window + q_chunk if window is not None else None
-
-    def chunk(start, qc):
-        qf = qc.astype(jnp.float32)
-        qpos = start + jnp.arange(q_chunk)
-        if band is not None and band < S:
-            kstart = jnp.clip(start - window, 0, S - band)
-            kc = jax.lax.dynamic_slice_in_dim(kx, kstart, band, axis=2)
-            vc = jax.lax.dynamic_slice_in_dim(vx, kstart, band, axis=2)
-            kpos = kstart + jnp.arange(band)
-        else:
-            kc, vc, kpos = kx, vx, jnp.arange(S)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kc.astype(jnp.float32)) * scale
-        mask = jnp.ones((q_chunk, kpos.shape[0]), bool)
-        if causal:
-            mask &= qpos[:, None] >= kpos[None, :]
-        if window is not None:
-            mask &= (qpos[:, None] - kpos[None, :]) < window
-        s = jnp.where(mask[None, None], s, -1e30)
-        p_ = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p_, vc.astype(jnp.float32)).astype(q.dtype)
-
-    qs = q.reshape(B, Hq, nq, q_chunk, D).transpose(2, 0, 1, 3, 4)
-    starts = jnp.arange(nq) * q_chunk
-    outs = jax.lax.scan(lambda _, xs: (None, chunk(xs[0], xs[1])), None,
-                        (starts, qs))[1]
-    return outs.transpose(1, 2, 0, 3, 4).reshape(B, Hq, S, Dv)
-
-
-def ssd(x, dt, A, Bm, Cm, D_skip, *, chunk: int = 128, impl: str = "xla",
-        return_final_state: bool = False):
-    """Mamba2 SSD scan. See ref.ssd_scan_ref for shapes.
-
-    return_final_state: also return the (B,H,P,N) state after the last token
-    (prefill path; uses the XLA chunked implementation, which carries it).
-    """
-    _check(impl)
-    if return_final_state or impl == "xla":
-        return _ssd_xla_chunked(x, dt, A, Bm, Cm, D_skip, chunk=chunk,
-                                return_final_state=return_final_state)
-    return _ssd.ssd_scan(x, dt, A, Bm, Cm, D_skip, chunk=chunk,
-                         interpret=(impl == "pallas_interpret"))
-
-
-def _ssd_xla_chunked(x, dt, A, Bm, Cm, D_skip, *, chunk: int = 128,
-                     return_final_state: bool = False):
-    """XLA path: same chunked block decomposition as the kernel, expressed in
-    jnp (scan over chunks) — O(S·L) not O(S^2), so long_500k prefill lowers.
-    """
-    import jax
-
-    B, S, H, P = x.shape
-    G, N = Bm.shape[2], Bm.shape[3]
-    rep = H // G
-    L = min(chunk, S)
-    pad = (-S) % L
-    if pad:  # dt=0, x=0 padding is exact: padded steps leave state unchanged
-        zf = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
-        x, dt, Bm, Cm = zf(x), zf(dt), zf(Bm), zf(Cm)
-        S_out, S = S, S + pad
-    else:
-        S_out = S
-    nc = S // L
-    f32 = jnp.float32
-
-    xf = x.astype(f32).reshape(B, nc, L, H, P)
-    dtf = dt.astype(f32).reshape(B, nc, L, H)
-    Bh = jnp.repeat(Bm.astype(f32), rep, axis=2).reshape(B, nc, L, H, N)
-    Ch = jnp.repeat(Cm.astype(f32), rep, axis=2).reshape(B, nc, L, H, N)
-    Af = A.astype(f32)
-
-    a = dtf * Af  # (B,nc,L,H)
-    cum = jnp.cumsum(a, axis=2)
-    tpos = jnp.arange(L)[:, None]
-    spos = jnp.arange(L)[None, :]
-    causal = (tpos >= spos)[None, :, :, None]  # (1,L,L,1)
-
-    def chunk_step(state, inp):
-        # state: (B,H,P,N); inp per-chunk slices
-        xc, dtc, cumc, bc, cc = inp  # (B,L,H,P),(B,L,H),(B,L,H),(B,L,H,N),(B,L,H,N)
-        y_inter = jnp.exp(cumc)[..., None] * jnp.einsum("blhn,bhpn->blhp", cc, state)
-        cb = jnp.einsum("blhn,bshn->blsh", cc, bc)  # (B,L,L,H)
-        # clamp the exponent: cum_t - cum_s <= 0 on the causal region; the
-        # masked t<s entries would overflow exp and NaN the where-gradient
-        decay = jnp.exp(jnp.minimum(cumc[:, :, None, :] - cumc[:, None, :, :], 0.0))
-        g = jnp.where(causal, cb * decay * dtc[:, None, :, :], 0.0)
-        y_intra = jnp.einsum("blsh,bshp->blhp", g, xc)
-        w = jnp.exp(cumc[:, -1:, :] - cumc) * dtc  # (B,L,H)
-        state = (jnp.exp(cumc[:, -1, :])[..., None, None] * state
-                 + jnp.einsum("blhp,blhn->bhpn", xc * w[..., None], bc))
-        return state, y_inter + y_intra
-
-    state0 = jnp.zeros((B, H, P, N), f32)
-    xs = (jnp.moveaxis(xf, 1, 0), jnp.moveaxis(dtf, 1, 0), jnp.moveaxis(cum, 1, 0),
-          jnp.moveaxis(Bh, 1, 0), jnp.moveaxis(Ch, 1, 0))
-    final_state, ys = jax.lax.scan(chunk_step, state0, xs)
-    y = jnp.moveaxis(ys, 0, 1).reshape(B, S, H, P)
-    y = y + D_skip.astype(f32)[None, None, :, None] * x.astype(f32)
-    y = y.astype(x.dtype)[:, :S_out]
-    if return_final_state:
-        return y, final_state
-    return y
-
-
-def rmsnorm(x, w, *, eps: float = 1e-6, impl: str = "xla"):
-    _check(impl)
-    if impl == "xla":
-        return ref.rmsnorm_ref(x, w, eps=eps)
-    return _rn.rmsnorm(x, w, eps=eps, interpret=(impl == "pallas_interpret"))
 
 
 def _resolve_block_f(F: int, K: int, num_t: int, impl: str,
@@ -513,19 +358,3 @@ def frontier_moments_with_grads(W, mus, sigmas, *, num_t: int = 1024,
     return _moments_grads(W, mus, sigmas, extra, num_t, impl, bf, z, dist_id,
                           param_grads=param_grads)
 
-
-def decode_attention(q, k_cache, v_cache, valid, *, sm_scale=None,
-                     impl: str = "xla", block_s: int = 512):
-    """Single-token attention over a KV cache (online-softmax streaming).
-
-    q: (B, Hkv, G, D); caches: (B, Hkv, S, D); valid: (S,) bool.
-    The Pallas path is the fix for the decode memory wall (EXPERIMENTS
-    §Perf D2): one pass over the cache instead of a materialized score chain.
-    """
-    _check(impl)
-    if impl == "xla":
-        return ref.decode_attention_ref(q, k_cache, v_cache, valid,
-                                        sm_scale=sm_scale)
-    return _fd.flash_decode(q, k_cache, v_cache, valid, sm_scale=sm_scale,
-                            block_s=block_s,
-                            interpret=(impl == "pallas_interpret"))

@@ -1,8 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the semantics; the kernels must match them (asserted by
-tests/test_kernels.py across shape/dtype sweeps, kernels run in
-interpret=True on CPU).
+tests/test_kernels.py, test_families.py and test_lane_packing.py, kernels
+run in interpret=True on CPU).
 
 The frontier oracles are family-generic: every completion-time family in
 ``core.distributions.FAMILIES`` — normal, lognormal, drift, empirical,
@@ -11,14 +11,9 @@ defective — flows through the ``dists.family_*`` dispatch on the static
 """
 from __future__ import annotations
 
-from typing import Optional
-
-import jax
 import jax.numpy as jnp
 
-__all__ = ["frontier_grid_ref", "frontier_grid_with_grads_ref",
-           "flash_attention_ref", "ssd_scan_ref", "rmsnorm_ref",
-           "decode_attention_ref"]
+__all__ = ["frontier_grid_ref", "frontier_grid_with_grads_ref"]
 
 # log-CDF clamp floor. Must be a NORMAL f32 (>= 1.18e-38): XLA CPU flushes
 # subnormals to zero, and a flushed floor turns the log/clip VJP into
@@ -236,93 +231,3 @@ def frontier_grid_with_grads_ref(W, mus, sigmas, num_t: int = 1024,
     else:
         dmu_e, dvar_e = zero_fk, zero_fk
     return (mu, var, dmu, dvar, dmu_m, dvar_m, dmu_s, dvar_s, dmu_e, dvar_e)
-
-
-def flash_attention_ref(q, k, v, *, causal: bool = True,
-                        window: Optional[int] = None, sm_scale: Optional[float] = None):
-    """Reference GQA attention. q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D).
-
-    Rectangular Sq != Sk supported (cross-attention); causal then aligns the
-    last query with the last key (standard self-attn when Sq == Sk).
-    """
-    B, Hq, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
-    group = Hq // Hkv
-    scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
-    kx = jnp.repeat(k, group, axis=1)
-    vx = jnp.repeat(v, group, axis=1)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        kx.astype(jnp.float32)) * scale
-    qpos = jnp.arange(Sq)[:, None] + (Sk - Sq)
-    kpos = jnp.arange(Sk)[None, :]
-    mask = jnp.ones((Sq, Sk), bool)
-    if causal:
-        mask &= qpos >= kpos
-    if window is not None:
-        mask &= (qpos - kpos) < window
-    logits = jnp.where(mask, logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, vx.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-def ssd_scan_ref(x, dt, A, Bm, Cm, D_skip=None):
-    """Naive sequential Mamba2 SSD recurrence (the semantics oracle).
-
-    x:  (B, S, H, P)   inputs per head
-    dt: (B, S, H)      positive step sizes (softplus already applied)
-    A:  (H,)           negative per-head decay rates
-    Bm: (B, S, G, N)   input projections (G groups, H % G == 0)
-    Cm: (B, S, G, N)   output projections
-    D_skip: (H,) or None — skip connection
-    Returns y: (B, S, H, P).
-
-        state_t = exp(dt_t A_h) state_{t-1} + dt_t * (B_t ⊗ x_t)
-        y_t     = C_t · state_t (+ D_h x_t)
-    """
-    B, S, H, P = x.shape
-    G, N = Bm.shape[2], Bm.shape[3]
-    rep = H // G
-    Bh = jnp.repeat(Bm, rep, axis=2)  # (B,S,H,N)
-    Ch = jnp.repeat(Cm, rep, axis=2)
-
-    xf = x.astype(jnp.float32)
-    dtf = dt.astype(jnp.float32)
-    Af = A.astype(jnp.float32)
-
-    def step(state, inp):
-        x_t, dt_t, b_t, c_t = inp  # (B,H,P) (B,H) (B,H,N) (B,H,N)
-        dA = jnp.exp(dt_t * Af)  # (B,H)
-        state = state * dA[..., None, None] + (dt_t[..., None, None]
-                                               * x_t[..., :, None] * b_t[..., None, :])
-        y_t = jnp.einsum("bhpn,bhn->bhp", state, c_t)
-        return state, y_t
-
-    state0 = jnp.zeros((B, H, P, N), jnp.float32)
-    xs = (jnp.moveaxis(xf, 1, 0), jnp.moveaxis(dtf, 1, 0),
-          jnp.moveaxis(Bh.astype(jnp.float32), 1, 0), jnp.moveaxis(Ch.astype(jnp.float32), 1, 0))
-    _, ys = jax.lax.scan(step, state0, xs)
-    y = jnp.moveaxis(ys, 0, 1)  # (B,S,H,P)
-    if D_skip is not None:
-        y = y + D_skip.astype(jnp.float32)[None, None, :, None] * xf
-    return y.astype(x.dtype)
-
-
-def rmsnorm_ref(x, w, eps: float = 1e-6):
-    """RMSNorm over the last axis."""
-    xf = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * rms * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def decode_attention_ref(q, k_cache, v_cache, valid, sm_scale=None):
-    """Single-token GQA attention oracle. q: (B, Hkv, G, D); caches
-    (B, Hkv, S, D); valid: (S,) bool -> (B, Hkv, G, D)."""
-    D = q.shape[-1]
-    scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
-    s = jnp.einsum("bkgd,bksd->bkgs", q.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * scale
-    s = jnp.where(valid[None, None, None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bksd->bkgd", p, v_cache.astype(jnp.float32))
-    return o.astype(q.dtype)

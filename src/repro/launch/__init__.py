@@ -1,8 +1,1 @@
-"""Launch layer: production meshes, sharding rules, dry-run, roofline, CLIs."""
-from .mesh import batch_axes, make_local_mesh, make_production_mesh
-from .roofline import HW, analyze_hlo, count_params, model_flops, roofline_terms
-from .shardings import batch_specs, cache_specs, named, param_specs, state_specs
-
-__all__ = ["batch_axes", "make_local_mesh", "make_production_mesh", "HW",
-           "analyze_hlo", "count_params", "model_flops", "roofline_terms",
-           "batch_specs", "cache_specs", "named", "param_specs", "state_specs"]
+"""Launch layer: the serving CLI, ``python -m repro.launch.serve``."""

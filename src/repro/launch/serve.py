@@ -1,7 +1,9 @@
 """Serving CLI: paper-partitioned request batching across replica groups.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m --tiny \
-        --batches 50 --requests 64 --policy frontier
+    PYTHONPATH=src python -m repro.launch.serve --batches 50 --requests 64 \
+        --policy frontier
+
+Replica-group service times come from simulated channels.
 
 ``--engine`` switches to the continuous-batching :class:`WorkflowEngine`:
 instead of one replica fleet per batch, every tick admits queued workflow
@@ -13,14 +15,11 @@ through one stacked launch per family group:
 """
 import argparse
 
-import jax
 import numpy as np
 
 from ..compile_cache import enable_compile_cache
-from ..configs import ARCHS, get_config
-from ..models import build_model
 from ..obs import trace as obs
-from ..serve import PartitionedBatcher, ReplicaGroup, ServeEngine
+from ..serve import PartitionedBatcher, ReplicaGroup
 from ..sim.cluster import Channel, ClusterSim
 
 
@@ -101,15 +100,10 @@ def _export_trace(prefix: str) -> None:
 def main() -> None:
     enable_compile_cache()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCHS, default="smollm-360m")
-    ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--batches", type=int, default=50)
     ap.add_argument("--requests", type=int, default=64)
-    ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--policy", default="frontier",
                     choices=("frontier", "equal", "inverse_mu"))
-    ap.add_argument("--execute", action="store_true",
-                    help="run real tiny-model generation per group")
     # closed-estimation-loop knobs (PR 4), threaded end-to-end into the
     # batcher's balancer: online family selection, risk-adjusted candidate
     # scoring, sensitivity-sized refresh cadence
@@ -151,27 +145,16 @@ def main() -> None:
         _run_engine(args)
         return
 
-    cfg = get_config(args.arch)
-    if args.tiny:
-        cfg = cfg.tiny()
     groups = [ReplicaGroup("fast"), ReplicaGroup("slow")]
-    if args.execute:
-        for g in groups:
-            m = build_model(cfg)
-            g.engine = ServeEngine(m, cfg)
-            g.params = m.init(jax.random.PRNGKey(0))
     sim = ClusterSim([Channel(mu=20.0, sigma=2.0), Channel(mu=14.0, sigma=5.0)])
     b = PartitionedBatcher(groups, policy=args.policy, sim=sim,
                            family=args.family, risk_lam=args.risk_lam,
                            adaptive_refresh=args.adaptive_refresh,
                            refresh_every=args.refresh_every)
     lat = []
-    rng = np.random.default_rng(0)
+    requests = np.arange(args.requests)
     for i in range(args.batches):
-        prompts = rng.integers(0, cfg.vocab_size,
-                               (args.requests, 16)).astype(np.int32)
-        t, counts, _ = b.run_batch(prompts, max_new=args.max_new,
-                                   execute=args.execute)
+        t, counts = b.run_batch(requests)
         lat.append(t)
         if i % 10 == 0:
             tick = b.last_tick

@@ -1,5 +1,5 @@
-"""Serving engine: prefill + decode with KV caches, the partitioned batcher
-(the paper's file-transfer scenario mapped to request routing), and the
+"""Serving tier: the partitioned batcher (the paper's file-transfer
+scenario mapped to request routing across replica groups) and the
 continuous-batching :class:`WorkflowEngine`.
 
 The engine is the serving-tier answer to the question the paper answers for
@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..configs.base import ModelConfig
 from ..kernels import autotune, ops
 from ..obs import events as obs_events
 from ..obs import names as obs_names
@@ -35,39 +34,16 @@ from ..sim.cluster import ClusterSim, WorkflowSim
 from ..workflow.solve import _project_simplex_masked, stack_rows
 from .telemetry import ServeTelemetry
 
-__all__ = ["ServeEngine", "PartitionedBatcher", "WorkflowEngine",
+__all__ = ["ReplicaGroup", "PartitionedBatcher", "WorkflowEngine",
            "row_pgd_step"]
-
-
-class ServeEngine:
-    """Single-replica engine: batched prefill then greedy decode."""
-
-    def __init__(self, model, cfg: ModelConfig):
-        self.model = model
-        self.cfg = cfg
-        self._prefill = jax.jit(lambda p, t, cl: model.prefill(p, t, cache_len=cl),
-                                static_argnums=2)
-        self._step = jax.jit(model.decode_step)
-
-    def generate(self, params, prompts: jnp.ndarray, max_new: int) -> jnp.ndarray:
-        """prompts: (B, S) int32. Greedy continuation of max_new tokens."""
-        B, S = prompts.shape
-        logits, cache = self._prefill(params, prompts, S + max_new)
-        tok = jnp.argmax(logits[:, -1:, :self.cfg.vocab_size], axis=-1)
-        outs = [tok]
-        for _ in range(max_new - 1):
-            logits, cache = self._step(params, cache, tok)
-            tok = jnp.argmax(logits[:, :, :self.cfg.vocab_size], axis=-1)
-            outs.append(tok)
-        return jnp.concatenate(outs, axis=1)
 
 
 @dataclass
 class ReplicaGroup:
-    """A serving channel: model replica set with its own speed distribution."""
+    """A serving channel: a replica set with its own speed distribution.
+
+    Its service times come from the batcher's simulated channels."""
     name: str
-    engine: Optional[ServeEngine] = None
-    params: Optional[dict] = None
 
 
 class PartitionedBatcher:
@@ -112,31 +88,19 @@ class PartitionedBatcher:
         (moves over time when ``family="auto"``)."""
         return self.balancer.selected_family.dist_id
 
-    def run_batch(self, prompts: np.ndarray, max_new: int = 8,
-                  execute: bool = False) -> Tuple[float, np.ndarray, list]:
-        """Route one batch. Returns (join_latency, counts, responses).
+    def run_batch(self, requests: np.ndarray) -> Tuple[float, np.ndarray]:
+        """Route one batch (one entry of ``requests`` per request). Returns
+        (join_latency, counts).
 
-        execute=True runs the actual models (tiny configs in examples);
-        latency always comes from the simulator channels (this container has
-        one CPU — the timing physics live in sim, as the paper's did in
-        background-process contention). Per-tick telemetry — including the
-        family the solve ran under, which is the interesting signal in
-        ``family="auto"`` mode — lands in ``self.last_tick``.
+        Latency comes from the simulator channels (the timing physics live
+        in sim, as the paper's did in background-process contention).
+        Per-tick telemetry — including the family the solve ran under,
+        which is the interesting signal in ``family="auto"`` mode — lands
+        in ``self.last_tick``.
         """
-        R = prompts.shape[0]
+        R = len(requests)
         counts = self.split(R)
         fam = self.selected_family
-        responses = [None] * len(self.groups)
-        if execute:
-            off = 0
-            for gi, c in enumerate(counts):
-                if c == 0:
-                    continue
-                g = self.groups[gi]
-                chunk = jnp.asarray(prompts[off:off + c])
-                responses[gi] = np.asarray(
-                    g.engine.generate(g.params, chunk, max_new))
-                off += c
         join_t, durs = self.sim.run_step(counts.astype(np.float64) / max(R, 1))
         self.balancer.observe(durs, counts.astype(np.float64) / max(R, 1))
         self.last_tick = {
@@ -145,15 +109,15 @@ class PartitionedBatcher:
             "counts": counts,
             "effective_refresh": self.balancer.effective_refresh,
         }
-        return join_t, counts, responses
+        return join_t, counts
 
     # ------------------------------------------------------------ persistence
     def state_dict(self) -> dict:
         """Balancer AND sim-world snapshot: a batcher restored from this
         replays bitwise-identical ticks (same splits, same simulated
         durations, same posterior updates) — see ckpt/store.py's
-        kill/restore tick-parity contract. Replica groups (model handles)
-        are code-side configuration, like the workflow balancer's DAG."""
+        kill/restore tick-parity contract. Replica groups are code-side
+        configuration, like the workflow balancer's DAG."""
         return {"balancer": self.balancer.state_dict(),
                 "sim": self.sim.state_dict()}
 

@@ -853,5 +853,5 @@ class TestServeFamilies:
         assert get_family(pb.balancer.family).dist_id == "lognormal"
         prompts = np.zeros((24, 4), np.int32)
         for _ in range(3):
-            join_t, counts, _ = pb.run_batch(prompts)
+            join_t, counts = pb.run_batch(prompts)
             assert counts.sum() == 24 and join_t > 0.0

@@ -128,12 +128,12 @@ class TestKillRestoreParity:
         # the manifest carries the balancer; the sim world rides inflight
         save_pipeline(str(tmp_path), 2, pb.balancer,
                       inflight={"sim": pb.sim.state_dict()})
-        join_sv, counts_sv, _ = pb.run_batch(prompts)
+        join_sv, counts_sv = pb.run_batch(prompts)
         bal2, inflight, _ = restore_pipeline(str(tmp_path))
         pb2 = PartitionedBatcher(groups)
         pb2.balancer = bal2
         pb2.sim = ClusterSim.from_state_dict(inflight["sim"])
-        join_rp, counts_rp, _ = pb2.run_batch(prompts)
+        join_rp, counts_rp = pb2.run_batch(prompts)
         assert join_sv == join_rp
         np.testing.assert_array_equal(counts_sv, counts_rp)
 

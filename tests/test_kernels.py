@@ -1,106 +1,14 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
+"""frontier_grid shape sweeps: Pallas (interpret=True) vs the pure-jnp oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from frontier_cases import close, oracle, run, stacked
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.frontier_grid import frontier_grid
-from repro.kernels.rmsnorm import rmsnorm
-from repro.kernels.ssd_scan import ssd_scan
 
 KEY = jax.random.PRNGKey(0)
-
-
-def _tol(dtype):
-    return dict(atol=5e-2, rtol=5e-2) if dtype == jnp.bfloat16 else \
-        dict(atol=2e-4, rtol=2e-4)
-
-
-@pytest.mark.parametrize("B,Hq,Hkv,S,D", [
-    (1, 2, 1, 128, 64), (2, 4, 2, 256, 64), (1, 8, 8, 128, 128),
-    (1, 2, 2, 512, 32),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("causal,window", [(True, None), (False, None), (True, 64)])
-def test_flash_attention_sweep(B, Hq, Hkv, S, D, dtype, causal, window):
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, Hq, S, D), dtype)
-    k = jax.random.normal(ks[1], (B, Hkv, S, D), dtype)
-    v = jax.random.normal(ks[2], (B, Hkv, S, D), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=128, block_k=128, interpret=True)
-    expect = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expect, np.float32), **_tol(dtype))
-
-
-def test_flash_attention_rectangular_cross():
-    """Cross-attention: Sq != Sk, non-causal."""
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (2, 4, 256, 64))
-    k = jax.random.normal(ks[1], (2, 4, 128, 64))
-    v = jax.random.normal(ks[2], (2, 4, 128, 64))
-    out = flash_attention(q, k, v, causal=False, interpret=True)
-    expect = ref.flash_attention_ref(q, k, v, causal=False)
-    np.testing.assert_allclose(out, expect, atol=2e-4, rtol=2e-4)
-
-
-@pytest.mark.parametrize("B,S,H,P,G,N,chunk", [
-    (1, 128, 2, 16, 1, 32, 64), (2, 256, 4, 32, 2, 64, 128),
-    (1, 64, 2, 16, 1, 32, 64), (1, 128, 4, 8, 1, 16, 32),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ssd_scan_sweep(B, S, H, P, G, N, chunk, dtype):
-    ks = jax.random.split(KEY, 5)
-    x = jax.random.normal(ks[0], (B, S, H, P), dtype)
-    dt = (jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))) * 0.5).astype(jnp.float32)
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = (jax.random.normal(ks[3], (B, S, G, N)) * 0.3).astype(dtype)
-    Cm = (jax.random.normal(ks[4], (B, S, G, N)) * 0.3).astype(dtype)
-    Dk = jnp.ones((H,)) * 0.5
-    out = ssd_scan(x, dt, A, Bm, Cm, Dk, chunk=chunk, interpret=True)
-    expect = ref.ssd_scan_ref(x, dt, A, Bm, Cm, Dk)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expect, np.float32),
-                               atol=0.05 if dtype == jnp.bfloat16 else 5e-4,
-                               rtol=0.05 if dtype == jnp.bfloat16 else 5e-4)
-
-
-def test_ssd_xla_chunked_matches_ref():
-    ks = jax.random.split(KEY, 5)
-    B, S, H, P, G, N = 1, 256, 2, 16, 1, 32
-    x = jax.random.normal(ks[0], (B, S, H, P))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))) * 0.5
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.3
-    Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.3
-    Dk = jnp.ones((H,)) * 0.5
-    out = ops.ssd(x, dt, A, Bm, Cm, Dk, impl="xla", chunk=64)
-    expect = ref.ssd_scan_ref(x, dt, A, Bm, Cm, Dk)
-    np.testing.assert_allclose(out, expect, atol=5e-5, rtol=5e-4)
-    # final-state return path matches, incl. a non-divisible length (padding)
-    y, state = ops.ssd(x, dt, A, Bm, Cm, Dk, impl="xla", chunk=64,
-                       return_final_state=True)
-    assert state.shape == (B, H, P, N)
-    np.testing.assert_allclose(y, expect, atol=5e-5, rtol=5e-4)
-    out_odd = ops.ssd(x[:, :200], dt[:, :200], A, Bm[:, :200], Cm[:, :200],
-                      Dk, impl="xla", chunk=64)
-    expect_odd = ref.ssd_scan_ref(x[:, :200], dt[:, :200], A, Bm[:, :200],
-                                  Cm[:, :200], Dk)
-    np.testing.assert_allclose(out_odd, expect_odd, atol=5e-5, rtol=5e-4)
-
-
-@pytest.mark.parametrize("rows,D", [(64, 96), (17, 128), (256, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_rmsnorm_sweep(rows, D, dtype):
-    x = jax.random.normal(KEY, (rows, D), dtype)
-    w = jax.random.normal(jax.random.fold_in(KEY, 1), (D,), dtype)
-    out = rmsnorm(x, w, interpret=True)
-    expect = ref.rmsnorm_ref(x, w)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expect, np.float32), **_tol(dtype))
 
 
 @pytest.mark.parametrize("F,K,num_t", [(128, 2, 512), (256, 8, 512), (64, 16, 256)])
@@ -126,48 +34,14 @@ def test_frontier_grid_matches_core_oracle():
     np.testing.assert_allclose(vk[0], vq, rtol=1e-3)
 
 
-def test_chunked_xla_attention_long():
-    """Scan-over-chunks path == dense ref, incl. SWA band slicing."""
-    ks = jax.random.split(KEY, 3)
-    B, H, S, D = 1, 2, 2048, 32
-    q = jax.random.normal(ks[0], (B, H, S, D))
-    k = jax.random.normal(ks[1], (B, H, S, D))
-    v = jax.random.normal(ks[2], (B, H, S, D))
-    for window in (None, 256):
-        out = ops.attention(q, k, v, causal=True, window=window, impl="xla",
-                            xla_q_chunk=512)
-        expect = ref.flash_attention_ref(q, k, v, causal=True, window=window)
-        np.testing.assert_allclose(out, expect, atol=2e-4, rtol=2e-4)
-
-
-@pytest.mark.parametrize("B,Hkv,G,S,D,block", [
-    (1, 2, 4, 512, 64, 128), (2, 4, 1, 1024, 64, 512), (1, 1, 8, 256, 128, 256),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_decode_sweep(B, Hkv, G, S, D, block, dtype):
-    from repro.kernels.flash_decode import flash_decode
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, Hkv, G, D), dtype)
-    k = jax.random.normal(ks[1], (B, Hkv, S, D), dtype)
-    v = jax.random.normal(ks[2], (B, Hkv, S, D), dtype)
-    valid = jnp.arange(S) < (S - S // 4)   # partially filled cache
-    out = flash_decode(q, k, v, valid, block_s=block, interpret=True)
-    expect = ref.decode_attention_ref(q, k, v, valid)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expect, np.float32), **_tol(dtype))
-
-
-def test_flash_decode_matches_model_local_decode():
-    """Kernel semantics == the model's decode attention path."""
-    from repro.kernels.flash_decode import flash_decode
-    from repro.models.attention import _local_decode
-    ks = jax.random.split(KEY, 3)
-    B, Hkv, G, S, D = 2, 2, 3, 256, 32
-    q4 = jax.random.normal(ks[0], (B, Hkv, G, D))
-    k = jax.random.normal(ks[1], (B, Hkv, S, D))
-    v = jax.random.normal(ks[2], (B, Hkv, S, D))
-    valid = jnp.arange(S) < 200
-    out = flash_decode(q4, k, v, valid, block_s=64, interpret=True)
-    expect = _local_decode(q4.reshape(B, Hkv * G, D), k, v, valid, G)
-    np.testing.assert_allclose(np.asarray(out.reshape(B, Hkv * G, D)),
-                               np.asarray(expect), atol=2e-4, rtol=2e-4)
+@pytest.mark.parametrize("mode", ["grad", "pgrad"])
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_ragged_stacked_rows_with_extra(impl, mode):
+    """11 per-row fleets in blocks of 4: ``ops`` pads the rows, their
+    statistics and the drift rates to 12 with copies of row 0, and every
+    real row's moments and adjoints are its own, as the oracle gives them
+    on the 11 rows unpadded."""
+    W, mus, sgs, extra = stacked("drift", 11, 5, seed=3)
+    got = run(mode, impl, W, mus, sgs, "drift", extra, 64, block_f=4)
+    assert {g.shape[0] for g in got} == {11}
+    close(got, oracle(mode, W, mus, sgs, "drift", extra, 64))
